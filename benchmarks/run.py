@@ -51,6 +51,8 @@ def main() -> None:
     ap.add_argument("--only", nargs="*", default=None)
     ap.add_argument("--fast", action="store_true")
     args = ap.parse_args()
+    from repro.launch import cache as cache_lib
+    cache_lib.use_compile_cache()
     names = args.only if args.only else MODULES
     print("name,us_per_call,derived")
     failures = 0

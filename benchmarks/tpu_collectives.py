@@ -31,7 +31,9 @@ print("JSON:" + json.dumps(out))
 
 def run():
     src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env = dict(os.environ, REPRO_SRC=src)
+    # Pin the CPU: the child wants 16 virtual host devices, never the
+    # chip a parent process may hold.
+    env = dict(os.environ, REPRO_SRC=src, JAX_PLATFORMS="cpu")
     proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-2000:]

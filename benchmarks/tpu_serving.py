@@ -496,8 +496,11 @@ def _measured_tp() -> dict:
         f.write(_TP_SCRIPT)
         script = f.name
     try:
+        # Pin the CPU: the child wants 8 virtual host devices, never the
+        # chip this process may hold.
         proc = subprocess.run([sys.executable, script, src],
-                              capture_output=True, text=True, timeout=600)
+                              capture_output=True, text=True, timeout=600,
+                              env=dict(os.environ, JAX_PLATFORMS="cpu"))
         assert proc.returncode == 0, proc.stderr[-3000:]
         line = [l for l in proc.stdout.splitlines()
                 if l.startswith("TP_RESULTS:")][0]

@@ -315,7 +315,10 @@ def _mesh_key(mesh_shape=None) -> str:
 
 def _cache_key(p: AttnProblem, tpu: hwmodel.TPUSpec,
                mesh_shape=None) -> str:
-    return (f"{tpu.name}:{_mesh_key(mesh_shape)}:sq={p.sq}:skv={p.skv}"
+    # Keyed by the live backend first (as ``calibration_key`` is): an
+    # entry a CPU run wrote is never read by a run on the chip.
+    return (f"{_backend_key()}:{tpu.name}:{_mesh_key(mesh_shape)}"
+            f":sq={p.sq}:skv={p.skv}"
             f":h={p.n_heads}:d={p.head_dim}:b={p.batch}"
             f":causal={int(p.causal)}:bytes={p.in_bytes}")
 

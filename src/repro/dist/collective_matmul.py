@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -58,7 +58,7 @@ def ag_matmul(x, w, mesh, axis: str = "model"):
 
     fn = shard_map(ring, mesh=mesh,
                    in_specs=(P(None, axis), P(None, None)),
-                   out_specs=P(None, None), check_rep=False)
+                   out_specs=P(None, None), check_vma=False)
     return fn(x, w)
 
 
@@ -106,7 +106,7 @@ def rs_matmul(x, w, mesh, axis: str = "model"):
 
     fn = shard_map(ring, mesh=mesh,
                    in_specs=(P(None, axis), P(None, None)),
-                   out_specs=P(None, axis), check_rep=False)
+                   out_specs=P(None, axis), check_vma=False)
     return fn(x, w)
 
 

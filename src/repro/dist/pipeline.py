@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -64,7 +64,7 @@ def gpipe(layer, mesh, axis: str = "stage"):
             lambda a: P(axis, *([None] * (a.ndim - 1))), weights)
         fn = shard_map(run, mesh=mesh,
                        in_specs=(w_specs, P(*([None] * micro.ndim))),
-                       out_specs=P(*([None] * micro.ndim)), check_rep=False)
+                       out_specs=P(*([None] * micro.ndim)), check_vma=False)
         return fn(weights, micro)
 
     return transform

@@ -1,9 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On the CPU container the kernels execute in interpret mode (the kernel body
-runs in Python for correctness validation); on a TPU backend they compile
-natively. Block shapes default to the microbench-informed autotuner's
-choices (``core/autotune``).
+On the CPU the kernels execute in interpret mode (the kernel body runs in
+Python for correctness validation); on a TPU backend they compile
+natively. ``native()`` is the one platform test: it also decides whether
+the serving engine routes attention through these kernels
+(``serve.engine.ServingEngine``). Block shapes default to the
+microbench-informed autotuner's choices (``core/autotune``).
 """
 
 from __future__ import annotations
@@ -19,8 +21,13 @@ from repro.kernels import pchase_probe as _pchase
 from repro.kernels import ssd_scan as _ssd
 
 
+def native() -> bool:
+    """True when the kernels compile for the chip (the TPU backend)."""
+    return jax.default_backend() == "tpu"
+
+
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return not native()
 
 
 def gemm(x, y, block=None):

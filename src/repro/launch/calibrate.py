@@ -19,6 +19,7 @@ import json
 import time
 
 from repro.core import autotune, calibrate
+from repro.launch import cache as cache_lib
 
 
 def main(argv=None):
@@ -30,6 +31,7 @@ def main(argv=None):
     ap.add_argument("--json", action="store_true",
                     help="emit the calibration report as JSON")
     args = ap.parse_args(argv)
+    cache_lib.use_compile_cache()
 
     backend = autotune._backend_key()
     persist = not args.no_persist

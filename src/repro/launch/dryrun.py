@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"      # placeholders, never a chip
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes, with NO device allocation (ShapeDtypeStruct inputs).

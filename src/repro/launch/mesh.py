@@ -10,18 +10,12 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-
-try:  # jax >= 0.5: meshes carry explicit axis types.
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: no axis_types kwarg; Auto is the default.
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -25,6 +25,7 @@ fault schedule on top:
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import time
@@ -34,8 +35,10 @@ import numpy as np
 
 from repro import configs
 from repro.core import autotune
+from repro.launch import cache as cache_lib
 from repro.launch import mesh as mesh_lib
 from repro.models import transformer as T
+from repro.serve import dist as serve_dist
 from repro.serve import traffic
 from repro.serve.engine import Request, ServeConfig, ServingEngine, SLOClass
 from repro.serve.faults import FaultInjector, canonical_schedule
@@ -175,6 +178,7 @@ def main(argv=None):
                           "cache entries (reproducibility escape hatch; "
                           "see repro.launch.calibrate)")
     args = ap.parse_args(argv)
+    cache_lib.use_compile_cache()
 
     if args.default_constants:
         os.environ[autotune.DEFAULT_CONSTANTS_ENV] = "1"
@@ -208,7 +212,6 @@ def main(argv=None):
         else configs.get_config(args.arch)
     if cfg.encoder is not None or cfg.n_frontend_tokens:
         raise SystemExit("serve launcher demo supports decoder-only archs")
-    params = T.init_params(jax.random.PRNGKey(args.seed), cfg)
     n_pages = None
     if args.paged and args.pool_frac < 1.0:
         # At least 2 (null page + one real page): a tiny fraction should
@@ -230,7 +233,14 @@ def main(argv=None):
         telemetry=not args.no_telemetry)
     if args.trace_out and args.no_telemetry:
         raise SystemExit("--trace-out needs telemetry (drop --no-telemetry)")
-    engine = ServingEngine(params, cfg, scfg, mesh=mesh)
+    # Weights are made in the compute dtype. On a mesh each device makes
+    # only its own shards, so no device ever holds the whole tree.
+    key = jax.random.PRNGKey(args.seed)
+    init = functools.partial(T.init_params, cfg=cfg, dtype=cfg.dtype)
+    if mesh is not None:
+        init = jax.jit(init, out_shardings=serve_dist.param_shardings(
+            jax.eval_shape(init, key), mesh))
+    engine = ServingEngine(init(key), cfg, scfg, mesh=mesh)
     t0 = time.time()
     if args.rate is not None:
         tcfg = traffic.TrafficConfig(
@@ -349,7 +359,7 @@ def main(argv=None):
               f"(open at ui.perfetto.dev or chrome://tracing)")
     for rid in sorted(finished):
         print(f"  req {rid}: {finished[rid][:10]}...")
-    return finished
+    return engine
 
 
 if __name__ == "__main__":

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro import configs
 from repro.data import DataConfig, SyntheticLMData
 from repro.dist import sharding as shd
+from repro.launch import cache as cache_lib
 from repro.launch import mesh as mesh_mod
 from repro.models import transformer as T
 from repro.optim import schedule
@@ -103,6 +104,7 @@ def main(argv=None):
     ap.add_argument("--mesh", default="none",
                     choices=["none", "single", "multi"])
     args, extra = ap.parse_known_args(argv)
+    cache_lib.use_compile_cache()
     if args.error_feedback:
         args.compress_grads = True
 

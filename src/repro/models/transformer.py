@@ -166,12 +166,14 @@ def _layer_apply(params: Params, cfg: ModelConfig, kind: str, x,
 # ----------------------------------------------------------------------------
 
 def _stack_init(key, cfg: ModelConfig) -> List[Params]:
-    """Per pattern position: params stacked over periods (leading dim)."""
+    """Per pattern position: params stacked over periods (leading dim).
+    vmap over the period keys gives the same values as one init per key,
+    stacked, but traces one layer instead of ``periods`` of them."""
     blocks = []
     for pos, kind in enumerate(cfg.pattern):
         keys = jax.random.split(jax.random.fold_in(key, pos), cfg.periods)
-        per_period = [_layer_init(k, cfg, kind, pos) for k in keys]
-        blocks.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per_period))
+        blocks.append(jax.vmap(
+            lambda k, kind=kind, pos=pos: _layer_init(k, cfg, kind, pos))(keys))
     return blocks
 
 
@@ -232,7 +234,21 @@ def _stack_apply(blocks: List[Params], cfg: ModelConfig, x, cross_kv=None,
 # Whole models
 # ----------------------------------------------------------------------------
 
-def init_params(key, cfg: ModelConfig) -> Params:
+def init_params(key, cfg: ModelConfig, dtype=jnp.float32) -> Params:
+    """Random weights, every leaf stored in ``dtype``.
+
+    float32 (the default) keeps full-precision master weights for
+    training. Serving passes ``cfg.dtype``: the leaves are made and cast
+    inside one jitted program, so a bf16 model never holds a float32 copy
+    of its tree on the device, and each use's ``.astype(x.dtype)`` in
+    ``layers`` is a no-op."""
+    if jnp.dtype(dtype) == jnp.float32:
+        return _init_params(key, cfg)
+    return jax.jit(lambda k: jax.tree.map(
+        lambda a: a.astype(dtype), _init_params(k, cfg)))(key)
+
+
+def _init_params(key, cfg: ModelConfig) -> Params:
     ks = jax.random.split(key, 6)
     p: Params = {
         "embed": layers.embedding_init(ks[0], cfg.vocab, cfg.d_model),
@@ -428,24 +444,29 @@ def init_paged_caches(cfg: ModelConfig, batch: int, max_len: int,
     assert n_pages >= 2, n_pages
     dtype = dtype or cfg.dtype
     max_pages = -(-max_len // page_size)
-    caches = []
-    for _ in cfg.pattern:
-        c = {
-            "kp": jnp.zeros((n_pages, page_size, cfg.n_kv_heads, cfg.dhead),
-                            dtype),
-            "vp": jnp.zeros((n_pages, page_size, cfg.n_kv_heads, cfg.dhead),
-                            dtype),
-            "pages": jnp.zeros((batch, max_pages), jnp.int32),
-            "index": jnp.zeros((batch,), jnp.int32),
-        }
-        stacked = jax.tree.map(
-            lambda a: jnp.broadcast_to(a[None], (cfg.periods,) + a.shape),
-            c)
-        caches.append(stacked)
-    if mesh is not None:
-        from repro.serve import dist as serve_dist
-        caches = serve_dist.shard_caches(caches, mesh, pool_axis)
-    return caches
+
+    def make():
+        caches = []
+        for _ in cfg.pattern:
+            c = {
+                "kp": jnp.zeros(
+                    (n_pages, page_size, cfg.n_kv_heads, cfg.dhead), dtype),
+                "vp": jnp.zeros(
+                    (n_pages, page_size, cfg.n_kv_heads, cfg.dhead), dtype),
+                "pages": jnp.zeros((batch, max_pages), jnp.int32),
+                "index": jnp.zeros((batch,), jnp.int32),
+            }
+            caches.append(jax.tree.map(
+                lambda a: jnp.broadcast_to(a[None], (cfg.periods,) + a.shape),
+                c))
+        return caches
+
+    if mesh is None:
+        return make()
+    # Each device makes only its own pages: none ever holds the whole pool.
+    from repro.serve import dist as serve_dist
+    return jax.jit(make, out_shardings=serve_dist.cache_shardings(
+        jax.eval_shape(make), mesh, pool_axis))()
 
 
 def cache_hbm_rows(caches) -> int:

@@ -46,7 +46,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist import sharding
@@ -60,6 +60,22 @@ def serve_ruleset(mesh, rules: Optional[dict] = None) -> sharding.Ruleset:
     """The serving engine's ruleset: TP params/activations (no FSDP — no
     per-token gather on the decode path) + the sharded page pool."""
     return sharding.Ruleset(mesh=mesh, rules=dict(rules or {}), fsdp=False)
+
+
+def param_shardings(params, mesh, ruleset=None):
+    """Tensor-parallel NamedSharding per weight leaf: each leaf gets the
+    spec its name resolves to under the serving ruleset (heads/mlp/vocab
+    over "model"; norms and non-divisible leaves replicate). ``params``
+    may hold arrays or shapes (``jax.eval_shape``), so weights can be made
+    already sharded."""
+    rs = ruleset or serve_ruleset(mesh)
+
+    def spec(path, leaf):
+        names = tuple(str(getattr(p, "key", getattr(p, "idx", p)))
+                      for p in path)
+        return NamedSharding(mesh, sharding.param_spec(names, leaf.shape, rs))
+
+    return jax.tree_util.tree_map_with_path(spec, params)
 
 
 def active_pool_mesh() -> Optional[Tuple[Any, str]]:
@@ -89,9 +105,11 @@ def pool_sharding(mesh, axis: str, ndim: int, page_dim: int):
     return NamedSharding(mesh, P(*spec))
 
 
-def shard_caches(caches, mesh, axis: str = "model"):
-    """Place paged caches on the mesh: kp/vp page-sharded (dim 1 — dim 0
-    is the period stack), page tables and write indices replicated."""
+def cache_shardings(caches, mesh, axis: str = "model"):
+    """NamedSharding per paged-cache leaf: kp/vp page-sharded (dim 1 —
+    dim 0 is the period stack), page tables and write indices
+    replicated. ``caches`` may hold arrays or shapes, so the pools can be
+    made already sharded."""
     repl = NamedSharding(mesh, P())
     out = []
     for c in caches:
@@ -100,12 +118,9 @@ def shard_caches(caches, mesh, axis: str = "model"):
             assert n_pages % int(dict(mesh.shape)[axis]) == 0, \
                 (n_pages, dict(mesh.shape))
             sh = pool_sharding(mesh, axis, c["kp"].ndim, page_dim=1)
-            out.append({"kp": jax.device_put(c["kp"], sh),
-                        "vp": jax.device_put(c["vp"], sh),
-                        "pages": jax.device_put(c["pages"], repl),
-                        "index": jax.device_put(c["index"], repl)})
+            out.append({k: sh if k in ("kp", "vp") else repl for k in c})
         else:
-            out.append({k: jax.device_put(v, repl) for k, v in c.items()})
+            out.append({k: repl for k in c})
     return out
 
 
@@ -134,7 +149,7 @@ def scatter_pages(kp, vp, k, v, page, row, mesh, axis: str = "model"):
                    in_specs=(pool, pool, P(None, None, None, None),
                              P(None, None, None, None), P(None, None),
                              P(None, None)),
-                   out_specs=(pool, pool), check_rep=False)
+                   out_specs=(pool, pool), check_vma=False)
     return fn(kp, vp, k, v, page, row)
 
 
@@ -171,5 +186,5 @@ def gather_pages(kp, vp, pages, mesh, axis: str = "model"):
                    in_specs=(pool, pool, P(None, None)),
                    out_specs=(P(None, None, None, None),
                               P(None, None, None, None)),
-                   check_rep=False)
+                   check_vma=False)
     return fn(kp, vp, pages)

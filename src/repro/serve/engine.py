@@ -287,6 +287,15 @@ class ServingEngine:
 
     def __init__(self, params, cfg: T.ModelConfig, serve_cfg: ServeConfig,
                  mesh=None):
+        from repro.kernels import ops as kernel_ops
+        if kernel_ops.native() and mesh is None:
+            # On the chip every executable attends through the Pallas
+            # kernels; the gather + sdpa branch stays the reference that
+            # tests and chip_smoke.py compare against. The mesh path
+            # keeps that branch: XLA cannot partition a Mosaic kernel
+            # over the mesh by itself (page-local attention per device
+            # is ROADMAP A6).
+            cfg = dataclasses.replace(cfg, use_flash=True)
         self.cfg = cfg
         self.scfg = serve_cfg
         self.mesh = mesh
@@ -496,21 +505,13 @@ class ServingEngine:
     # -- distributed placement ------------------------------------------------
 
     def _shard_params(self, params, mesh):
-        """Tensor-parallel placement: each leaf lands with the spec its
-        name resolves to under the serving ruleset (heads/mlp/vocab over
-        "model"; norms and non-divisible leaves replicate). device_put
-        up front — the executables then see committed shardings and emit
-        no surprise resharding on the hot path."""
-        from repro.dist import sharding as shd
-
-        def put(path, leaf):
-            names = tuple(str(getattr(p, "key", getattr(p, "idx", p)))
-                          for p in path)
-            spec = shd.param_spec(names, leaf.shape, self._ruleset)
-            return jax.device_put(
-                leaf, jax.sharding.NamedSharding(mesh, spec))
-
-        return jax.tree_util.tree_map_with_path(put, params)
+        """Tensor-parallel placement (``serve.dist.param_shardings``).
+        device_put up front — the executables then see committed
+        shardings and emit no surprise resharding on the hot path. Leaves
+        made already sharded (``launch.serve``) stay where they are."""
+        from repro.serve import dist as serve_dist
+        return jax.device_put(
+            params, serve_dist.param_shardings(params, mesh, self._ruleset))
 
     # -- jitted executables ---------------------------------------------------
 

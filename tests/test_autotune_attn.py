@@ -146,6 +146,32 @@ def test_tuning_cache_roundtrip(tmp_path, monkeypatch):
     assert terms2["time_s"] == pytest.approx(terms["time_s"])
 
 
+def test_tuning_cache_cpu_entry_not_read_on_tpu(tmp_path, monkeypatch):
+    """Entries are keyed by the live backend: a block a CPU run stored
+    (the cache file is untracked, so it travels with a copied tree) is a
+    miss for a run on the chip, which derives its own choice."""
+    import jax
+    path = tmp_path / "cache.json"
+    monkeypatch.setattr(autotune, "TUNING_CACHE_PATH", str(path))
+    monkeypatch.setattr(autotune, "_tuning_cache", None)
+    p = _problem(1024, 1024)
+    analytic, _ = autotune.choose_attn_block(p, use_cache=False)
+    planted = next(c for c in autotune.candidate_attn_blocks(p)
+                   if c != analytic)
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    cpu_key = autotune._cache_key(p, hwmodel.DEFAULT_TPU)
+    path.write_text(json.dumps({cpu_key: {
+        "block_q": planted.block_q, "block_k": planted.block_k,
+        "time_s": 1.0, "terms": {}}}))
+    blk, terms = autotune.choose_attn_block(p)
+    assert blk == planted and terms["cached"] is True
+    monkeypatch.setattr(autotune, "_tuning_cache", None)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert autotune._cache_key(p, hwmodel.DEFAULT_TPU) != cpu_key
+    blk, terms = autotune.choose_attn_block(p)
+    assert "cached" not in terms and blk == analytic
+
+
 # ----------------------------------------------------------------------------
 # Chunked-prefill cost model
 # ----------------------------------------------------------------------------

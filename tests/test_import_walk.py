@@ -21,10 +21,11 @@ def test_every_repro_module_imports():
     names = _walk("repro")
     assert len(names) > 50, f"suspiciously few modules found: {len(names)}"
     failures = {}
-    # launch.dryrun sets XLA_FLAGS (subprocess entry point); importing it
-    # here is safe since the backend is already initialized, but the env
-    # mutation must not leak into later subprocess-spawning tests.
-    xla_flags = os.environ.get("XLA_FLAGS")
+    # launch.dryrun sets XLA_FLAGS and JAX_PLATFORMS (subprocess entry
+    # point); importing it here is safe since the backend is already
+    # initialized, but the env mutation must not leak into later
+    # subprocess-spawning tests.
+    saved = {k: os.environ.get(k) for k in ("XLA_FLAGS", "JAX_PLATFORMS")}
     try:
         for name in sorted(names):
             try:
@@ -32,10 +33,11 @@ def test_every_repro_module_imports():
             except Exception as e:  # noqa: BLE001 — collect all, report once
                 failures[name] = f"{type(e).__name__}: {e}"
     finally:
-        if xla_flags is None:
-            os.environ.pop("XLA_FLAGS", None)
-        else:
-            os.environ["XLA_FLAGS"] = xla_flags
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
     assert not failures, "\n".join(f"{k}: {v}" for k, v in failures.items())
 
 

@@ -108,8 +108,8 @@ def test_tuning_cache_keyed_by_mesh_shape(tmp_path, monkeypatch):
     assert any(":dev1:" in k for k in keys), keys
     assert any(":mesh(model=8):" in k for k in keys), keys
     # Same problem, same backend: only the mesh component differs.
-    assert {k.split(":", 2)[2] for k in keys} == \
-        {keys[0].split(":", 2)[2]}
+    assert {k.split(":", 3)[3] for k in keys} == \
+        {keys[0].split(":", 3)[3]}
     # Both entries hit on re-lookup (no clobbering).
     assert autotune.choose_attn_block(p, mesh_shape="dev1")[0] == b1
     assert autotune.choose_attn_block(p, mesh_shape={"model": 8})[0] == b8
@@ -240,6 +240,7 @@ def test_rechoose_k_tracks_accept_rate():
 MULTIDEV_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import sys
 sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp, numpy as np
@@ -248,7 +249,7 @@ from repro.launch import mesh as mesh_lib
 from repro.dist import collective_matmul as cm
 from repro.models import transformer as T
 from repro.serve.engine import Request, ServeConfig, ServingEngine
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 assert jax.device_count() == 8
@@ -270,7 +271,7 @@ def ar_matmul(x, w):   # the naive row-parallel baseline rs_matmul halves
         return jax.lax.psum(xb @ wb, "model")
     return shard_map(body, mesh=mesh, in_specs=(P(None, "model"),
                      P(None, None)), out_specs=P(None, None),
-                     check_rep=False)(x, w)
+                     check_vma=False)(x, w)
 
 for name, fn in (("rs", lambda: cm.rs_matmul(x, w, mesh, "model")),
                  ("ag", lambda: cm.ag_matmul(x, w, mesh, "model")),
@@ -350,6 +351,29 @@ assert k1 == k8, (k1, k8)
 assert ev8.verify_traces == 1, ev8.verify_traces
 results["spec"] = "ok"
 
+# 3. Weights made already sharded (the mesh launcher's init) match the
+#    one-device bf16 init bit for bit, each leaf carries the sharding the
+#    engine places it with, and the big leaves really are split. The
+#    engine's page pools are made sharded too, and start zeroed.
+import functools
+from repro.serve import dist as serve_dist
+key = jax.random.PRNGKey(0)
+init = functools.partial(T.init_params, cfg=cfg, dtype=jnp.bfloat16)
+shardings = serve_dist.param_shardings(jax.eval_shape(init, key), mesh)
+made = jax.jit(init, out_shardings=shardings)(key)
+eng = ServingEngine(made, cfg, ServeConfig(**greedy), mesh=mesh)
+for a, b, p, sh in zip(jax.tree.leaves(made), jax.tree.leaves(init(key)),
+                       jax.tree.leaves(eng.params), jax.tree.leaves(shardings)):
+    assert a.dtype == jnp.bfloat16 and a.sharding == sh == p.sharding
+    np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                  np.asarray(b, np.float32))
+assert any(not s.is_fully_replicated for s in jax.tree.leaves(shardings))
+for c, sh in zip(jax.tree.leaves(eng.caches), jax.tree.leaves(
+        serve_dist.cache_shardings(eng.caches, mesh))):
+    assert c.sharding == sh and not np.asarray(c).any()
+assert not eng.caches[0]["kp"].sharding.is_fully_replicated
+results["sharded_init"] = "ok"
+
 print("MULTIDEV_RESULTS:" + ",".join(f"{k}={v}"
                                      for k, v in results.items()))
 """
@@ -363,5 +387,6 @@ def test_multidevice_serving_parity(tmp_path):
     proc = subprocess.run([sys.executable, str(script), src],
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
-    for part in ("rs_matmul", "greedy", "sampled", "preempt", "spec"):
+    for part in ("rs_matmul", "greedy", "sampled", "preempt", "spec",
+                 "sharded_init"):
         assert f"{part}=ok" in proc.stdout, proc.stdout

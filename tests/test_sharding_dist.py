@@ -118,6 +118,7 @@ def test_error_feedback_threads_through_train_step():
 MULTIDEV_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import sys
 sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp, numpy as np
