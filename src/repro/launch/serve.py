@@ -20,13 +20,17 @@ fault schedule on top:
       --max-preemptions 3 --degrade --tenant \\
       "name=paid,priority=2,weight=1" --tenant \\
       "name=free,weight=3,rate=2,burst=16,ttft=32"
+
+--profile-dir DIR runs the serving loop under the JAX profiler: one
+timeline with the engine's ``serve.*`` spans on the host and the
+device's ops, which opens in Perfetto or TensorBoard, and a per-phase
+summary of it in the report.
 """
 
 from __future__ import annotations
 
 import argparse
 import functools
-import json
 import os
 import time
 
@@ -39,6 +43,7 @@ from repro.launch import cache as cache_lib
 from repro.launch import mesh as mesh_lib
 from repro.models import transformer as T
 from repro.serve import dist as serve_dist
+from repro.serve import profile as profile_mod
 from repro.serve import traffic
 from repro.serve.engine import Request, ServeConfig, ServingEngine, SLOClass
 from repro.serve.faults import FaultInjector, canonical_schedule
@@ -74,6 +79,22 @@ def _parse_tenant(spec: str):
     if set(kv) - known:
         raise SystemExit(f"--tenant unknown keys {sorted(set(kv) - known)}")
     return slo, tcls
+
+
+def _profile_report(run_dir: str) -> None:
+    """Where the ticks of the profiled run went, by engine phase."""
+    rep = profile_mod.summary(profile_mod.load(run_dir))
+    waits = list(rep["queue_ms"].values())
+    wait = (f", admission wait p90 {np.percentile(waits, 90):.2f} ms"
+            if waits else "")
+    print(f"  profile: {run_dir} (open in Perfetto or TensorBoard): "
+          f"{rep['ticks']} ticks, host work {rep['tick_host_ms']:.2f} "
+          f"ms/tick besides the fetches{wait}")
+    if "idle_s" in rep:
+        idle = sorted(rep["idle_s"].items(), key=lambda x: -x[1])
+        print(f"    device idle {sum(rep['idle_s'].values()) * 1e3:.1f} ms"
+              f" of {rep['window_s'] * 1e3:.1f} ms, by phase: "
+              + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in idle))
 
 
 def main(argv=None):
@@ -165,10 +186,19 @@ def main(argv=None):
     obs = ap.add_argument_group(
         "observability (serve/telemetry.py)",
         "Structured tick traces and wall-clock spans are on by default "
-        "(ring-buffered, overhead-bounded, stream-transparent).")
-    obs.add_argument("--trace-out", default=None, metavar="PATH",
-                     help="write the Chrome-trace/Perfetto JSON timeline "
-                          "here after the run (open at ui.perfetto.dev)")
+        "(ring-buffered, overhead-bounded, stream-transparent). Each span "
+        "is also a profiler annotation named serve.<phase>: serve.tick "
+        "around a tick, and inside it serve.admit (serve.admit.request per "
+        "request, with rid and queue_ms), serve.prefill "
+        "(serve.prefill_chunk, serve.prefill_fetch), serve.pages, "
+        "serve.decode (serve.decode.dispatch, serve.decode.fetch), "
+        "serve.record and serve.positions; serve.draft and "
+        "serve.spec_verify on the speculative path.")
+    obs.add_argument("--profile-dir", default=None, metavar="DIR",
+                     help="run the serving loop under jax.profiler and "
+                          "write its trace (.xplane.pb) here: the serve.* "
+                          "spans and the device's ops on one clock; open "
+                          "it in Perfetto or TensorBoard")
     obs.add_argument("--no-telemetry", action="store_true",
                      help="disable the event ring and wall-clock spans "
                           "(decision counters stay exact either way)")
@@ -231,8 +261,9 @@ def main(argv=None):
                           if args.spec_probe_every else None),
         spec_probe_every=args.spec_probe_every,
         telemetry=not args.no_telemetry)
-    if args.trace_out and args.no_telemetry:
-        raise SystemExit("--trace-out needs telemetry (drop --no-telemetry)")
+    if args.profile_dir and args.no_telemetry:
+        raise SystemExit("--profile-dir needs telemetry (drop "
+                         "--no-telemetry)")
     # Weights are made in the compute dtype. On a mesh each device makes
     # only its own shards, so no device ever holds the whole tree.
     key = jax.random.PRNGKey(args.seed)
@@ -241,6 +272,8 @@ def main(argv=None):
         init = jax.jit(init, out_shardings=serve_dist.param_shardings(
             jax.eval_shape(init, key), mesh))
     engine = ServingEngine(init(key), cfg, scfg, mesh=mesh)
+    if args.profile_dir:
+        jax.profiler.start_trace(args.profile_dir)
     t0 = time.time()
     if args.rate is not None:
         tcfg = traffic.TrafficConfig(
@@ -298,6 +331,8 @@ def main(argv=None):
         toks = sum(len(v) for v in finished.values())
         print(f"served {len(finished)} requests, {toks} tokens "
               f"in {dt:.2f}s ({toks/dt:.1f} tok/s)")
+    if args.profile_dir:
+        jax.profiler.stop_trace()
     # Which constant set priced this session's choose_* decisions —
     # operators need to tell a stale calibration from a fresh one.
     const = engine.constants
@@ -352,11 +387,8 @@ def main(argv=None):
                   f"exec-mean={st['execute_mean_s'] * 1e3:.2f} ms "
                   f"(compile {st['compile_n']}x "
                   f"{st['compile_s'] * 1e3:.1f} ms)")
-    if args.trace_out:
-        with open(args.trace_out, "w") as f:
-            json.dump(tel.chrome_trace(), f)
-        print(f"  wrote {args.trace_out} "
-              f"(open at ui.perfetto.dev or chrome://tracing)")
+    if args.profile_dir:
+        _profile_report(args.profile_dir)
     for rid in sorted(finished):
         print(f"  req {rid}: {finished[rid][:10]}...")
     return engine

@@ -60,6 +60,7 @@ KV/SSM cache of the cell's sequence length, caches donated in-place.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -174,12 +175,13 @@ class ServeConfig:
     # accept rate clears (requires spec_adapt_every). None keeps the
     # disable regime terminal (legacy).
     # -- observability (``serve.telemetry``) -------------------------------
-    telemetry: bool = True       # event ring + wall-clock spans. Disabling
-    # drops the ring buffers and every perf_counter read; the decision
+    telemetry: bool = True       # event ring + spans (profiler
+    # annotations and wall-clock aggregates). Disabling drops the ring
+    # buffers, the annotations and every perf_counter read; the decision
     # *aggregates* (admission_rejections, shed_by_class, ...) stay exact
     # either way, and token streams are bit-identical traced or not.
     trace_capacity: int = 4096   # ring-buffer entries per stream (events,
-    # spans, tick times); eviction never touches the aggregates.
+    # tick times); eviction never touches the aggregates.
 
 
 def prefill(params, cfg: T.ModelConfig, tokens, caches,
@@ -274,6 +276,9 @@ class Request:
     preempt_count: int = 0       # times evicted back to the queue
     readmitted_at: Optional[int] = None  # tick of last re-admission
     # (preemption-storm guard input; None until first preemption)
+    submit_s: Optional[float] = None     # host clock at first submit
+    # (``time.perf_counter``): the first admission's span carries the
+    # queue wait counted from it
 
 
 class ServingEngine:
@@ -703,13 +708,15 @@ class ServingEngine:
         if fresh:
             self.telemetry.emit(self.ticks, "page_alloc", slot=slot,
                                 n=len(pages))
-        have = len(self.pool.slot_pages[slot]) - len(pages)
-        cols = jnp.arange(have, have + len(pages))
-        vals = jnp.asarray(pages, jnp.int32)
-        self.caches = [
-            dict(c, pages=c["pages"].at[:, slot, cols].set(vals))
-            for c in self.caches
-        ]
+        with self.telemetry.span("pages", self.ticks, slot=slot,
+                                 n=len(pages)):
+            have = len(self.pool.slot_pages[slot]) - len(pages)
+            cols = jnp.arange(have, have + len(pages))
+            vals = jnp.asarray(pages, jnp.int32)
+            self.caches = [
+                dict(c, pages=c["pages"].at[:, slot, cols].set(vals))
+                for c in self.caches
+            ]
 
     # -- prefix cache (``paged.PrefixIndex``) ---------------------------------
 
@@ -722,12 +729,13 @@ class ServingEngine:
         old, new = self.pool.cow(slot, pos)
         self.telemetry.emit(self.ticks, "cow_copy", slot=slot,
                             old=old, new=new, pos=pos)
-        self.caches = [
-            dict(c, kp=c["kp"].at[:, new].set(c["kp"][:, old]),
-                 vp=c["vp"].at[:, new].set(c["vp"][:, old]),
-                 pages=c["pages"].at[:, slot, pos].set(new))
-            for c in self.caches
-        ]
+        with self.telemetry.span("pages", self.ticks, slot=slot, n=1):
+            self.caches = [
+                dict(c, kp=c["kp"].at[:, new].set(c["kp"][:, old]),
+                     vp=c["vp"].at[:, new].set(c["vp"][:, old]),
+                     pages=c["pages"].at[:, slot, pos].set(new))
+                for c in self.caches
+            ]
 
     def _cow_range(self, slot: int, lo: int, hi: int) -> None:
         """Split any *shared* page backing rows [lo, hi) before a write
@@ -965,6 +973,8 @@ class ServingEngine:
     # -- request lifecycle ----------------------------------------------------
 
     def submit(self, req: Request):
+        if req.submit_s is None:
+            req.submit_s = time.perf_counter()
         self.submit_tick.setdefault(req.rid, self.ticks)
         self._arrival_seq.setdefault(req.rid, self._n_arrivals)
         self._n_arrivals += 1
@@ -1240,63 +1250,87 @@ class ServingEngine:
                             rclass=req.rclass, need=first,
                             free=self.pool.free_pages)
                         return        # hold: everyone waits for pages
-                    self.queue.pop(qi)
-                    self._charge_bucket(req)
-                    self.slots[i] = req
-                    if req.preempt_count:
-                        req.readmitted_at = self.ticks   # storm guard
-                    self._prefilling[i] = cursor
-                    self._slot_seq[i] = self._admit_seq
-                    self._admit_seq += 1
-                    self.telemetry.emit(
-                        self.ticks, "admit", rid=req.rid, slot=i,
-                        rclass=req.rclass, rows=plen,
-                        readmit=req.preempt_count)
-                    if self.prefix is not None:
-                        if n_hit:
-                            self.pool.share(i, hit_pages)
-                            self._append_pages(i, hit_pages, fresh=False)
-                            self.telemetry.emit(
-                                self.ticks, "prefix_hit", rid=req.rid,
-                                slot=i, pages=n_hit, rows=cursor)
-                            self.telemetry.count("prefix_hit_pages",
-                                                 n_hit)
-                        else:
-                            self.telemetry.emit(
-                                self.ticks, "prefix_miss", rid=req.rid,
-                                slot=i)
-                        self._chain[i] = (hit_digest, n_hit)
-                    if cow_at is not None:
-                        self._cow_page(i, cow_at)
-                    self._append_pages(i, self.pool.alloc(i, suffix_need))
+                    with self._admit_span(req, i):
+                        self.queue.pop(qi)
+                        self._charge_bucket(req)
+                        self.slots[i] = req
+                        if req.preempt_count:
+                            req.readmitted_at = self.ticks   # storm guard
+                        self._prefilling[i] = cursor
+                        self._slot_seq[i] = self._admit_seq
+                        self._admit_seq += 1
+                        self.telemetry.emit(
+                            self.ticks, "admit", rid=req.rid, slot=i,
+                            rclass=req.rclass, rows=plen,
+                            readmit=req.preempt_count)
+                        if self.prefix is not None:
+                            if n_hit:
+                                self.pool.share(i, hit_pages)
+                                self._append_pages(i, hit_pages, fresh=False)
+                                self.telemetry.emit(
+                                    self.ticks, "prefix_hit", rid=req.rid,
+                                    slot=i, pages=n_hit, rows=cursor)
+                                self.telemetry.count("prefix_hit_pages",
+                                                     n_hit)
+                            else:
+                                self.telemetry.emit(
+                                    self.ticks, "prefix_miss", rid=req.rid,
+                                    slot=i)
+                            self._chain[i] = (hit_digest, n_hit)
+                        if cow_at is not None:
+                            self._cow_page(i, cow_at)
+                        self._append_pages(i, self.pool.alloc(i, suffix_need))
                     break             # chunks run in _prefill_tick
                 prompt = self._effective_prompt(req)
                 bucket = self.bucket_for(len(prompt))
                 assert len(prompt) <= bucket <= self.scfg.max_len, \
                     (len(prompt), bucket, self.scfg.max_len)
-                self.queue.pop(qi)
-                self._charge_bucket(req)
-                self.telemetry.emit(
-                    self.ticks, "admit", rid=req.rid, slot=i,
-                    rclass=req.rclass, rows=len(prompt),
-                    readmit=req.preempt_count)
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :len(prompt)] = prompt
-                with self.telemetry.span("prefill_bucket", self.ticks,
-                                         slot=i) as sp:
-                    n0 = self.prefill_traces.get(bucket, 0)
-                    tok, self.caches = self._prefill_fn(bucket)(
-                        self.params, jnp.asarray(padded),
-                        jnp.int32(len(prompt)), jnp.int32(i), self.caches,
-                        self._emit_key(req))
-                    sp.compile = self.prefill_traces.get(bucket, 0) > n0
-                self.slots[i] = req
-                self._slot_seq[i] = self._admit_seq
-                self._admit_seq += 1
-                tok = int(np.asarray(tok))
-                if not self._record(i, req, tok):
-                    self.last_tok = self.last_tok.at[i].set(tok)
+                with self._admit_span(req, i):
+                    self.queue.pop(qi)
+                    self._charge_bucket(req)
+                    self.telemetry.emit(
+                        self.ticks, "admit", rid=req.rid, slot=i,
+                        rclass=req.rclass, rows=len(prompt),
+                        readmit=req.preempt_count)
+                    padded = np.zeros((1, bucket), np.int32)
+                    padded[0, :len(prompt)] = prompt
+                    with self.telemetry.span("prefill_bucket", self.ticks,
+                                             slot=i) as sp:
+                        n0 = self.prefill_traces.get(bucket, 0)
+                        tok, self.caches = self._prefill_fn(bucket)(
+                            self.params, jnp.asarray(padded),
+                            jnp.int32(len(prompt)), jnp.int32(i), self.caches,
+                            self._emit_key(req))
+                        sp.compile = self.prefill_traces.get(bucket, 0) > n0
+                    self.slots[i] = req
+                    self._slot_seq[i] = self._admit_seq
+                    self._admit_seq += 1
+                    self._first_token(i, req, tok)
                 break
+
+    def _admit_span(self, req: Request, slot: int):
+        """The ``admit.request`` span of one request's install into
+        ``slot``. A first admission carries ``queue_ms``, the host time
+        since ``submit``; a re-admission after preemption carries
+        ``readmit`` (its preemption count) instead."""
+        if req.preempt_count:
+            return self.telemetry.span("admit.request", self.ticks, slot=slot,
+                                       rid=req.rid, readmit=req.preempt_count)
+        return self.telemetry.span(
+            "admit.request", self.ticks, slot=slot, rid=req.rid,
+            queue_ms=1e3 * (time.perf_counter() - req.submit_s))
+
+    def _first_token(self, i: int, req: Request, tok) -> None:
+        """Fetch the token a completed prompt sampled (this blocks until
+        the prefill finishes on the device) and record it."""
+        tel = self.telemetry
+        with tel.span("prefill_fetch", self.ticks, rid=req.rid):
+            tok = int(np.asarray(tok))
+        with tel.span("record", self.ticks) as sp:
+            done = self._record(i, req, tok)
+            if not done:
+                self.last_tok = self.last_tok.at[i].set(tok)
+            sp.note(n_finished=int(done))
 
     def _prefill_order(self) -> List[int]:
         """Mid-prefill slots in shortest-remaining-first order with aging
@@ -1376,7 +1410,8 @@ class ServingEngine:
             tel = self.telemetry
             tel.emit(self.ticks, "prefill_chunk", rid=req.rid, slot=i,
                      start=cursor, rows=n)
-            with tel.span("prefill_chunk", self.ticks, slot=i) as sp:
+            with tel.span("prefill_chunk", self.ticks, slot=i,
+                          rid=req.rid) as sp:
                 n0 = self.prefill_traces.get(self.chunk, 0)
                 tok, self.caches = self._chunk_fn(
                     self.params, jnp.asarray(chunk_toks), jnp.int32(cursor),
@@ -1392,9 +1427,7 @@ class ServingEngine:
                 self._prefilling[i] = end
                 continue
             del self._prefilling[i]            # prefill complete
-            tok = int(np.asarray(tok))
-            if not self._record(i, req, tok):
-                self.last_tok = self.last_tok.at[i].set(tok)
+            self._first_token(i, req, tok)
 
     def _update_pressure(self) -> None:
         """Load-shedding downshift latch (``ServeConfig.degrade``): the
@@ -1459,13 +1492,16 @@ class ServingEngine:
         speculative draft/verify step (``spec_k > 0``) — for all
         decode-active slots; returns #slots making progress.
 
-        The whole tick runs under a wall-clock span (plus per-phase
-        spans inside): purely host-observed timing — no device syncs or
-        transfers are added, so the traced tick does exactly the work an
-        untraced tick does."""
-        tel = self.telemetry
-        t0 = tel.clock()
+        The whole tick runs under the ``tick`` span, with a span per
+        host phase inside (``serve.telemetry``): purely host-observed
+        timing — no device syncs or transfers are added, so the traced
+        tick does exactly the work an untraced tick does."""
         self.ticks += 1
+        with self.telemetry.span("tick", self.ticks):
+            return self._tick()
+
+    def _tick(self) -> int:
+        tel = self.telemetry
         self._update_pressure()
         with tel.span("admit", self.ticks):
             self._admit()
@@ -1475,7 +1511,6 @@ class ServingEngine:
         active = [i for i, s in enumerate(self.slots)
                   if s is not None and i not in self._prefilling]
         if not active:
-            tel.tick_done(self.ticks, t0)
             return len(self._prefilling)
         n = len(active) + len(self._prefilling)
         k = self._spec_width()
@@ -1485,7 +1520,6 @@ class ServingEngine:
         else:
             self._decode_tick(active)
         self._reset_prefill_positions()
-        tel.tick_done(self.ticks, t0)
         return n
 
     def _maybe_adapt_k(self) -> None:
@@ -1527,19 +1561,28 @@ class ServingEngine:
         rids, ts = self._rid_ts(active)
         with tel.span("decode", self.ticks) as sp:
             n0 = self.decode_traces
-            nxt, self.caches = self._step(self.params, self.last_tok,
-                                          self.caches, rids, ts)
-            nxt_host = np.asarray(nxt).copy()
-            sp.compile = self.decode_traces > n0
-        active_set = set(active)
-        for i in range(self.scfg.batch):
-            if i in active_set:
-                if not self._record(i, self.slots[i], int(nxt_host[i])):
-                    continue
-            # Freed or empty slot: park the fed-back token at 0 so stale
-            # output can't alias eos_id (and decodes stay deterministic).
-            nxt_host[i] = 0
-        self.last_tok = jnp.asarray(nxt_host, jnp.int32)
+            with tel.span("decode.dispatch", self.ticks) as dp:
+                nxt, self.caches = self._step(self.params, self.last_tok,
+                                              self.caches, rids, ts)
+                dp.compile = self.decode_traces > n0
+            # The fetch waits for the device to finish the step.
+            with tel.span("decode.fetch", self.ticks):
+                nxt_host = np.asarray(nxt).copy()
+            sp.compile = dp.compile
+        with tel.span("record", self.ticks) as sp:
+            active_set = set(active)
+            n_fin = 0
+            for i in range(self.scfg.batch):
+                if i in active_set:
+                    if not self._record(i, self.slots[i], int(nxt_host[i])):
+                        continue
+                    n_fin += 1
+                # Freed or empty slot: park the fed-back token at 0 so
+                # stale output can't alias eos_id (and decodes stay
+                # deterministic).
+                nxt_host[i] = 0
+            self.last_tok = jnp.asarray(nxt_host, jnp.int32)
+            sp.note(n_finished=n_fin)
 
     def _spec_tick(self, active: List[int],
                    k: Optional[int] = None) -> None:
@@ -1586,13 +1629,28 @@ class ServingEngine:
         rids, t0s = self._rid_ts(active)
         with tel.span("spec_verify", self.ticks) as sp:
             n0 = self.verify_traces
-            picks, self.caches = self._verify_fn(
-                self.params, jnp.asarray(tokens), self.caches, rids, t0s)
-            picks = np.asarray(picks)
-            sp.compile = self.verify_traces > n0
+            with tel.span("spec_verify.dispatch", self.ticks) as dp:
+                picks, self.caches = self._verify_fn(
+                    self.params, jnp.asarray(tokens), self.caches, rids, t0s)
+                dp.compile = self.verify_traces > n0
+            with tel.span("spec_verify.fetch", self.ticks):
+                picks = np.asarray(picks)
+            sp.compile = dp.compile
+        with tel.span("record", self.ticks) as sp:
+            sp.note(n_finished=self._record_verified(active, tokens, picks,
+                                                     base_len, n_prop))
+
+    def _record_verified(self, active: List[int], tokens: np.ndarray,
+                         picks: np.ndarray, base_len: Dict[int, int],
+                         n_prop: Dict[int, int]) -> int:
+        """Record each slot's accepted drafts and bonus token, roll the
+        write positions back to the live lengths and feed the last
+        tokens back; returns how many requests finished."""
+        tel = self.telemetry
         last = np.zeros((self.scfg.batch,), np.int32)
         cols: List[int] = []
         vals: List[int] = []
+        n_fin = 0
         for i in active:
             req = self.slots[i]
             # Score only what the drafter actually proposed: a zero-padded
@@ -1614,6 +1672,7 @@ class ServingEngine:
             # over these events).
             tel.emit(self.ticks, "spec_verify", rid=req.rid, slot=i,
                      proposed=n_prop[i], accepted=accepted, emitted=n_rec)
+            n_fin += done
             if not done:
                 # Live rows gained: the pending token plus n_rec - 1
                 # accepted drafts (the last emitted token is the unwritten
@@ -1631,6 +1690,7 @@ class ServingEngine:
         # reset in _reset_prefill_positions; empty slots drift through
         # the null page exactly like a plain tick, just k+1 wide.
         self.last_tok = jnp.asarray(last, jnp.int32)
+        return n_fin
 
     def _reset_prefill_positions(self) -> None:
         """The batched decode/verify step advanced every slot's write
@@ -1640,11 +1700,12 @@ class ServingEngine:
         correctly."""
         if not self._prefilling:
             return
-        items = sorted(self._prefilling.items())
-        cols = jnp.asarray([i for i, _ in items], jnp.int32)
-        vals = jnp.asarray([v for _, v in items], jnp.int32)
-        self.caches = [dict(c, index=c["index"].at[:, cols].set(vals))
-                       for c in self.caches]
+        with self.telemetry.span("positions", self.ticks):
+            items = sorted(self._prefilling.items())
+            cols = jnp.asarray([i for i, _ in items], jnp.int32)
+            vals = jnp.asarray([v for _, v in items], jnp.int32)
+            self.caches = [dict(c, index=c["index"].at[:, cols].set(vals))
+                           for c in self.caches]
 
     def run_until_drained(self, max_ticks: int = 10000) -> Dict[int, List[int]]:
         for _ in range(max_ticks):

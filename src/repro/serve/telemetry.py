@@ -2,7 +2,7 @@
 
 The source paper dissects Volta by instrumenting tight loops and reading
 the clocks; this module applies the same probe-and-compare discipline to
-our own serving stack. Three surfaces, one bookkeeping home:
+our own serving stack. Two surfaces, one bookkeeping home:
 
   * **Event trace** — a ring-buffered, schema-versioned stream of typed
     tick events (``admit``, ``shed``, ``preempt``, ``degrade_enter`` /
@@ -16,20 +16,24 @@ our own serving stack. Three surfaces, one bookkeeping home:
     side of ``emit`` runs even when tracing is disabled (and even after
     ring eviction), so the counters stay exact while the ring bounds
     memory.
-  * **Wall-clock spans** — ``perf_counter`` spans around the decode /
-    verify / chunk executables and the host-side scheduling phases, with
-    trace-vs-execute separation (the first call of each executable is
-    flagged ``compile`` via the engine's trace-time counters — exact,
-    not heuristic), plus a per-tick wall-time histogram (p50/p99). Spans
-    measure *host-observed* time: dispatch plus whatever synchronization
-    the engine already performs. No device syncs or host<->device
-    transfers are added anywhere — instrumentation is purely
-    observational and the traced engine's token streams are bit-identical
-    to an untraced engine's (gated by tests/test_telemetry.py).
-  * **Exporters** — ``chrome_trace()`` emits a Chrome-trace/Perfetto JSON
-    timeline (one track per engine phase, one per slot; load it at
-    ``ui.perfetto.dev`` or ``chrome://tracing``); ``metrics()`` flattens
-    everything into one scalar dict for operator reports and bench cells.
+  * **Spans** — one API, ``Telemetry.span(name, tick, slot, **meta)``,
+    around every host phase of a tick (admission, page-table updates,
+    prefill chunks, the decode dispatch and the fetch that waits for it,
+    recording, position resets). Each span enters a
+    ``jax.profiler.TraceAnnotation`` named ``serve.<name>`` that carries
+    its metadata: under a profiler session (``launch/serve.py
+    --profile-dir``) the spans land in the trace's host plane, on the
+    same clock as the device's ops, so every idle gap of the device is
+    named by the engine phase the host was in. With no session the
+    annotation costs well under a microsecond. Each span also keeps a
+    ``perf_counter`` aggregate (``span_stats()``, with exact first-call
+    ``compile`` flags from the engine's trace counters), and the
+    ``tick`` span feeds a per-tick wall-time histogram
+    (``tick_stats()``). Spans wrap code the engine already runs: no
+    device syncs or host<->device transfers are added, and the traced
+    engine's token streams are bit-identical to an untraced engine's
+    (gated by tests/test_telemetry.py). ``serve.profile`` reads the
+    spans back from a trace.
 
 ``drift_report`` is the model-vs-measured gate: it compares the
 ``core.autotune`` cost-model predictions (``paged_decode_model``,
@@ -47,6 +51,7 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 TRACE_SCHEMA_VERSION = 1
 
@@ -74,27 +79,34 @@ EVENT_KINDS = frozenset({
 
 
 class _Span:
-    """Context manager recording one wall-clock span. ``compile`` is set
-    by the caller from the engine's trace-time counter delta (exact
-    first-call detection); it must be assigned *inside* the block."""
+    """Context manager around one host phase: a profiler annotation
+    ``serve.<name>`` for as long as it is open, and a wall-clock
+    aggregate when it closes. ``compile`` is set by the caller from the
+    engine's trace-time counter delta (exact first-call detection); it
+    must be assigned *inside* the block. ``note(**meta)`` adds metadata
+    known only at the end of the span."""
 
-    __slots__ = ("_tel", "name", "tick", "slot", "compile", "_t0")
+    __slots__ = ("_tel", "name", "tick", "compile", "_ann", "_t0")
 
-    def __init__(self, tel: "Telemetry", name: str, tick: int,
-                 slot: Optional[int]):
+    def __init__(self, tel: "Telemetry", name: str, tick: int, meta: dict):
         self._tel = tel
         self.name = name
         self.tick = tick
-        self.slot = slot
         self.compile = False
+        self._ann = TraceAnnotation("serve." + name, tick=tick, **meta)
+
+    def note(self, **meta) -> None:
+        self._ann.set_metadata(**meta)
 
     def __enter__(self) -> "_Span":
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._tel._record_span(self, self._t0,
-                               time.perf_counter() - self._t0)
+        dur = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        self._tel._record_span(self, dur)
 
 
 class _NullSpan:
@@ -104,6 +116,9 @@ class _NullSpan:
 
     def __init__(self):
         self.compile = False
+
+    def note(self, **meta) -> None:
+        pass
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -121,9 +136,9 @@ class Telemetry:
     Aggregates (``counters``, ``shed_by_class``, ``preemption_log``) are
     updated by every ``emit``/``count`` call regardless of ``enabled`` —
     they are the backing store for the engine's legacy counter views and
-    must stay exact. The *ring buffers* (events, spans, tick times) and
-    the ``perf_counter`` reads are what ``enabled`` gates: a disabled
-    engine pays only dict arithmetic.
+    must stay exact. The *ring buffers* (events, tick times), the spans
+    with their profiler annotations and the ``perf_counter`` reads are
+    what ``enabled`` gates: a disabled engine pays only dict arithmetic.
     """
 
     def __init__(self, enabled: bool = True, capacity: int = 4096):
@@ -133,8 +148,6 @@ class Telemetry:
         self.schema_version = TRACE_SCHEMA_VERSION
         # Ring entries: (t_rel_s, tick, kind, payload_dict).
         self.events: deque = deque(maxlen=capacity)
-        # Ring entries: (name, t0_rel_s, dur_s, tick, slot, compile).
-        self.spans: deque = deque(maxlen=capacity)
         # Ring entries: (tick, dur_s) — percentile window.
         self.tick_times: deque = deque(maxlen=capacity)
         self.dropped_events = 0          # ring evictions (aggregates exact)
@@ -160,8 +173,7 @@ class Telemetry:
         entry is appended only when tracing is enabled."""
         assert kind in EVENT_KINDS, kind
         # .item(): numpy scalars (token counts, lengths) must not leak
-        # into the aggregates or the ring — chrome_trace()/metrics()
-        # json-serialize these as-is.
+        # into the aggregates or the ring.
         payload = {k: (v.item() if hasattr(v, "item") else v)
                    for k, v in payload.items()}
         c = self.counters
@@ -186,14 +198,19 @@ class Telemetry:
         self.events.append(
             (time.perf_counter() - self._epoch, tick, kind, payload))
 
-    def span(self, name: str, tick: int,
-             slot: Optional[int] = None):
-        """Wall-clock span context manager; no-op when disabled."""
+    def span(self, name: str, tick: int, slot: Optional[int] = None,
+             **meta):
+        """Span context manager around one host phase (see ``_Span``);
+        ``slot`` and ``meta`` ride on its profiler annotation. The
+        ``tick`` span, around a whole tick, also feeds ``tick_stats()``.
+        A no-op when disabled."""
         if not self.enabled:
             return _NULL_SPAN
-        return _Span(self, name, tick, slot)
+        if slot is not None:
+            meta["slot"] = slot
+        return _Span(self, name, tick, meta)
 
-    def _record_span(self, sp: _Span, t0: float, dur: float) -> None:
+    def _record_span(self, sp: _Span, dur: float) -> None:
         agg = self._span_agg.get(sp.name)
         if agg is None:
             agg = self._span_agg[sp.name] = [0, 0.0, 0.0, 0, 0.0]
@@ -203,28 +220,16 @@ class Telemetry:
         if sp.compile:
             agg[3] += 1
             agg[4] += dur
-        self.spans.append((sp.name, t0 - self._epoch, dur, sp.tick,
-                           sp.slot, sp.compile))
-
-    def clock(self) -> float:
-        """Tick-start timestamp (0.0 when disabled — tick_done ignores)."""
-        return time.perf_counter() if self.enabled else 0.0
-
-    def tick_done(self, tick: int, t0: float) -> None:
-        """Close the whole-tick wall span opened by ``clock()``."""
-        if not self.enabled:
-            return
-        dur = time.perf_counter() - t0
-        self._tick_n += 1
-        self._tick_total_s += dur
-        self.tick_times.append((tick, dur))
+        if sp.name == "tick":
+            self._tick_n += 1
+            self._tick_total_s += dur
+            self.tick_times.append((sp.tick, dur))
 
     def reset(self) -> None:
         """Drop everything — rings, aggregates, epoch. The bench warm-up
         boundary: compile spans and warm-up events must not pollute the
         measured cells."""
         self.events.clear()
-        self.spans.clear()
         self.tick_times.clear()
         self.dropped_events = 0
         self.counters.clear()
@@ -273,82 +278,6 @@ class Telemetry:
                 "execute_mean_s": (total - cs) / en if en else 0.0,
             }
         return out
-
-    # -- exporters ------------------------------------------------------------
-
-    def metrics(self) -> Dict[str, Any]:
-        """Everything as one flat scalar dict (operator reports, bench
-        cells). Keys: ``count_*`` aggregates, ``tick_*`` histogram,
-        ``span_<name>_*`` per-span stats."""
-        out: Dict[str, Any] = {
-            "schema_version": self.schema_version,
-            "enabled": self.enabled,
-            "events_in_ring": len(self.events),
-            "events_dropped": self.dropped_events,
-        }
-        for k in sorted(self.counters):
-            out[f"count_{k}"] = self.counters[k]
-        for k, v in self.tick_stats().items():
-            out[f"tick_{k}"] = v
-        for name, st in sorted(self.span_stats().items()):
-            out[f"span_{name}_n"] = st["n"]
-            out[f"span_{name}_mean_s"] = st["mean_s"]
-            out[f"span_{name}_compile_n"] = st["compile_n"]
-            out[f"span_{name}_execute_mean_s"] = st["execute_mean_s"]
-        return out
-
-    def chrome_trace(self) -> Dict[str, Any]:
-        """Chrome-trace/Perfetto JSON (the ``traceEvents`` array format).
-
-        One track (tid) per engine phase (``phase:decode``, ...) carries
-        the wall-clock spans as complete events (ph="X"); per-slot tracks
-        (``slot:0``, ...) carry slot-attributed spans (prefill chunks)
-        and the decision events as instants (ph="i"). Counter tracks
-        (ph="C") reconstruct pool occupancy, queue depth, and the live
-        speculation width from the decision events, so calibration runs
-        and degradation-ladder transitions read off one timeline.
-        Timestamps are microseconds relative to the telemetry epoch.
-        Write with ``json.dump`` and open at ui.perfetto.dev or
-        chrome://tracing."""
-        tev = []
-        for name, t0, dur, tick, slot, comp in self.spans:
-            tid = f"slot:{slot}" if slot is not None else f"phase:{name}"
-            tev.append({"name": name, "ph": "X", "pid": 0, "tid": tid,
-                        "ts": t0 * 1e6, "dur": dur * 1e6,
-                        "args": {"tick": tick, "compile": comp}})
-        # Counter tracks, integrated from the decision events in ring
-        # order. The ring may have evicted the prefix of the run, so the
-        # integrals are clamped at zero — the *shape* (admission waves,
-        # preemption storms, k collapsing under degradation) is what the
-        # timeline is for; exact totals live in the aggregates.
-        pool = queue = 0
-        for t, tick, kind, payload in self.events:
-            slot = payload.get("slot")
-            tid = f"slot:{slot}" if slot is not None else "phase:events"
-            tev.append({"name": kind, "ph": "i", "s": "t", "pid": 0,
-                        "tid": tid, "ts": t * 1e6,
-                        "args": dict(payload, tick=tick)})
-            ts = t * 1e6
-            if kind in ("page_alloc", "page_free"):
-                pool += payload.get("n", 0) * (1 if kind == "page_alloc"
-                                               else -1)
-                pool = max(0, pool)
-                tev.append({"name": "pool_pages", "ph": "C", "pid": 0,
-                            "ts": ts, "args": {"pages": pool}})
-            elif kind in ("submit", "admit", "shed", "preempt"):
-                queue += 1 if kind in ("submit", "preempt") else -1
-                queue = max(0, queue)
-                tev.append({"name": "queue_depth", "ph": "C", "pid": 0,
-                            "ts": ts, "args": {"requests": queue}})
-            elif kind == "spec_verify":
-                tev.append({"name": "spec_k_live", "ph": "C", "pid": 0,
-                            "ts": ts,
-                            "args": {"k": payload.get("proposed", 0)}})
-            elif kind == "probe_tick":
-                tev.append({"name": "spec_k_live", "ph": "C", "pid": 0,
-                            "ts": ts, "args": {"k": 1}})
-        return {"traceEvents": tev, "displayTimeUnit": "ms",
-                "otherData": {"schema_version": self.schema_version}}
 
 
 # -- model-vs-measured drift gate ---------------------------------------------

@@ -4,7 +4,8 @@ bit-identical to an untraced engine's on every path (greedy, sampled,
 speculative, faulted, preempting) — the event trace reconciles exactly
 against the legacy counter views and the page pool's conservation law,
 ring eviction bounds memory without corrupting aggregates, compile
-detection is exact, the exporters emit valid JSON, and the
+detection is exact, the spans land in a profiler trace as ``serve.*``
+events (and a disabled telemetry writes none), and the
 model-vs-measured drift gate records finite positive ratios."""
 
 import json
@@ -16,7 +17,7 @@ import pytest
 from repro import configs
 from repro.core import autotune
 from repro.models import transformer as T
-from repro.serve import telemetry, traffic
+from repro.serve import profile, telemetry, traffic
 from repro.serve.engine import Request, ServeConfig, ServingEngine, SLOClass
 from repro.serve.faults import FaultInjector, canonical_schedule
 from repro.serve.paged import PageAllocator
@@ -215,7 +216,7 @@ def test_disabled_telemetry_keeps_counters_exact(model):
                   dict(rate=3.0, n_requests=24))
     on, _ = _run(model, _overload_kw(), dict(rate=3.0, n_requests=24))
     assert len(off.telemetry.events) == 0
-    assert len(off.telemetry.spans) == 0
+    assert off.telemetry.span_stats() == {}
     assert off.telemetry.tick_stats()["n"] == 0
     assert off.telemetry.counters == on.telemetry.counters
     assert off.admission_rejections == on.admission_rejections
@@ -249,33 +250,76 @@ def test_compile_flags_and_tick_histogram(model):
 
 
 # ----------------------------------------------------------------------------
-# Exporters: Perfetto JSON + flat metrics + wall-clock summary fields
+# Profiler: the spans on the device's clock, as serve.* host events
 # ----------------------------------------------------------------------------
 
-def test_chrome_trace_is_valid_json_with_tracks(model):
-    eng, _ = _run(model, _overload_kw(), dict(rate=2.0, n_requests=12))
-    tr = eng.telemetry.chrome_trace()
-    blob = json.dumps(tr)            # numpy leakage would raise here
-    back = json.loads(blob)
-    assert back["otherData"]["schema_version"] == \
-        telemetry.TRACE_SCHEMA_VERSION
-    evs = back["traceEvents"]
-    assert evs
-    phases = {e["tid"] for e in evs if e["ph"] == "X"}
-    assert "phase:decode" in phases
-    assert any(t.startswith("slot:") for t in phases)   # prefill chunks
-    # Counter tracks ride along as ph="C" events: pool occupancy and
-    # queue depth are always emitted on a paged overload run.
-    counters = {e["name"] for e in evs if e["ph"] == "C"}
-    assert {"pool_pages", "queue_depth"} <= counters
-    for e in evs:
-        assert e["ph"] in ("X", "i", "C")
-        assert isinstance(e["ts"], float)
-        if e["ph"] == "X":
-            assert e["dur"] >= 0.0
-        if e["ph"] == "C":
-            (val,) = e["args"].values()   # one series per counter event
-            assert isinstance(val, int) and val >= 0
+def _profiled(model, tmp_path, **scfg_kw):
+    """A tiny paged engine drained under a profiler session; returns the
+    engine and the serve.* spans read back from the trace."""
+    cfg, params = model
+    eng = ServingEngine(params, cfg, _scfg(n_pages=17, **scfg_kw))
+    for rid in range(3):
+        eng.submit(Request(rid=rid, prompt=np.arange(
+            3, 3 + 9 + 8 * rid, dtype=np.int32), max_new=4))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run_until_drained()
+    finally:
+        jax.profiler.stop_trace()
+    return eng, profile.load(str(tmp_path))["spans"]
+
+
+def test_profiler_trace_holds_the_tick_phases(model, tmp_path):
+    """Each decode tick's serve.tick span holds the decode dispatch, then
+    the fetch that waits for it, then the record loop; every request's
+    first admission carries its rid and queue wait."""
+    eng, spans = _profiled(model, tmp_path)
+    ticks = [s for s in spans if s[2] == "serve.tick"]
+    assert len(ticks) == eng.ticks
+    assert [s[3]["tick"] for s in ticks] == list(range(1, eng.ticks + 1))
+
+    def first(name, a, b):
+        return min((s for s in spans if s[2] == name and a <= s[0]
+                    and s[1] <= b), default=None)
+
+    n_decode = 0
+    for a, b, _, meta in ticks:
+        disp = first("serve.decode.dispatch", a, b)
+        if disp is None:
+            continue
+        n_decode += 1
+        fetch = first("serve.decode.fetch", a, b)
+        rec = first("serve.record", fetch[1], b)
+        assert disp[1] <= fetch[0] and rec is not None
+        assert disp[3]["tick"] == fetch[3]["tick"] == meta["tick"]
+        assert rec[3]["n_finished"] >= 0
+    assert n_decode == eng.telemetry.span_stats()["decode"]["n"]
+    admits = [s for s in spans if s[2] == "serve.admit.request"]
+    assert sorted(s[3]["rid"] for s in admits) == [0, 1, 2]
+    assert all(s[3]["queue_ms"] >= 0 for s in admits)
+    assert profile.queue_ms(spans).keys() == {0, 1, 2}
+    names = {s[2] for s in spans}
+    assert {"serve.admit", "serve.prefill", "serve.prefill_chunk",
+            "serve.prefill_fetch", "serve.pages", "serve.decode",
+            "serve.positions"} <= names
+
+
+def test_profiled_tokens_bit_identical(model, tmp_path):
+    """A profiler session moves no token."""
+    on, _ = _profiled(model, tmp_path)
+    cfg, params = model
+    off = ServingEngine(params, cfg, _scfg(n_pages=17))
+    for rid in range(3):
+        off.submit(Request(rid=rid, prompt=np.arange(
+            3, 3 + 9 + 8 * rid, dtype=np.int32), max_new=4))
+    off.run_until_drained()
+    assert on.finished == off.finished and on.ticks == off.ticks
+
+
+def test_disabled_telemetry_writes_no_profiler_event(model, tmp_path):
+    eng, spans = _profiled(model, tmp_path, telemetry=False)
+    assert eng.ticks > 0 and len(eng.finished) == 3
+    assert spans == []
 
 
 def test_metrics_flat_and_summary_wall_clock(model):
@@ -284,13 +328,6 @@ def test_metrics_flat_and_summary_wall_clock(model):
                                  ttft_ms=1e6, tpot_ms=1e6),)
     eng, arr = _run(model, _overload_kw(),
                     dict(rate=2.0, n_requests=12, classes=tcls))
-    m = eng.telemetry.metrics()
-    assert m["schema_version"] == telemetry.TRACE_SCHEMA_VERSION
-    assert m["enabled"] is True
-    assert m["count_admit"] >= 1
-    assert m["span_decode_n"] >= 1
-    for v in m.values():              # flat: scalars only
-        assert isinstance(v, (bool, int, float, str)), v
     s = traffic.summarize(eng, arr, classes=tcls)
     assert s["tick_wall_s_mean"] > 0
     assert s["tick_wall_s_p99"] >= s["tick_wall_s_p50"]
@@ -363,10 +400,12 @@ def test_reset_clears_rings_and_aggregates():
         tel.emit(i, "admit", rid=i, rclass="default")
     with tel.span("decode", 0):
         pass
-    tel.tick_done(0, tel.clock())
+    with tel.span("tick", 0):
+        pass
     assert tel.dropped_events == 2
+    assert tel.tick_stats()["n"] == 1
     tel.reset()
-    assert len(tel.events) == 0 and len(tel.spans) == 0
+    assert len(tel.events) == 0 and tel.span_stats() == {}
     assert tel.dropped_events == 0
     assert tel.counters == {} and tel.tick_stats()["n"] == 0
 
