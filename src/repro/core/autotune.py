@@ -28,9 +28,10 @@ namespace and preferred by ``resolve_constants``:
 constant             default   measured by (``core.calibrate`` probe)
 ===================  ========  ========================================
 ``PAGE_LOOKUP_S``    5e-8 s    page-walk slope: ``flash_decode_paged``
-                               vs contiguous ``flash_decode`` across a
-                               page-table-size sweep, regressed per
-                               visited K/V block
+                               (one page per step) vs contiguous
+                               ``flash_decode`` in page-sized blocks
+                               across a page-table-size sweep,
+                               regressed per visited K/V block
 ``CHUNK_DISPATCH_S`` 5e-6 s    per-chunk execute span of the chunked
                                prefill executable (telemetry spans,
                                compile-separated)

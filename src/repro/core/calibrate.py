@@ -13,11 +13,12 @@ become *measured* per backend+mesh:
   page_lookup_s     sweep page-table sizes through the real
                     ``flash_decode_paged`` executable at fixed context,
                     time the contiguous ``flash_decode`` at the same
-                    lengths, and regress both against visited K blocks:
-                    the *difference of slopes* is the per-block cost of
-                    walking the table (the pchase trick — vary one knob,
-                    read the marginal cost off the line, subtract the
-                    part a contiguous layout also pays).
+                    lengths with one page per K/V block, and regress
+                    both against visited blocks: the *difference of
+                    slopes* is the per-block cost of walking the table
+                    (the pchase trick — vary one knob, read the marginal
+                    cost off the line, subtract the part a contiguous
+                    layout also pays).
   hbm_bandwidth     timed device round-trips of an ``a + 1`` stream at
                     serving-relevant sizes, per dtype; the best observed
                     rate (2 x nbytes per call: read + write).
@@ -103,14 +104,15 @@ def probe_dispatch(fast: bool = False) -> ProbeResult:
 def probe_page_lookup(fast: bool = False) -> ProbeResult:
     """Page-walk slope: time ``flash_decode_paged`` across page-table
     sizes and subtract the contiguous ``flash_decode`` slope at the same
-    context lengths — the residual marginal cost per visited K block is
-    the table lookup itself."""
+    context lengths, streamed in page-sized blocks — the residual marginal
+    cost per visited K block (one page of one kv head) is the table lookup
+    itself."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops
 
     batch, kvh, heads, d = 2, 1, 2, 32
-    page_size = block_k = 8
+    page_size = 8
     tables = (2, 4, 8) if fast else (2, 4, 8, 16)
     n = 3 if fast else 7
     key = jax.random.PRNGKey(0)
@@ -133,13 +135,13 @@ def probe_page_lookup(fast: bool = False) -> ProbeResult:
             batch, max_len, kvh, d)
         tp, _, _ = _best_of(
             lambda: ops.flash_decode_paged(
-                q, k_pages, v_pages, page_table, lengths,
-                block_k=block_k).block_until_ready(), n)
+                q, k_pages, v_pages, page_table,
+                lengths).block_until_ready(), n)
         tc, _, _ = _best_of(
             lambda: ops.flash_decode(
                 q, k_flat, v_flat, lengths,
-                block_k=block_k).block_until_ready(), n)
-        visited.append(batch * kvh * n_tables)   # K blocks touched/call
+                block_k=page_size).block_until_ready(), n)
+        visited.append(batch * kvh * n_tables)   # K blocks (pages)/call
         t_paged.append(tp)
         t_contig.append(tc)
     slope_paged = float(np.polyfit(visited, t_paged, 1)[0])
@@ -151,6 +153,7 @@ def probe_page_lookup(fast: bool = False) -> ProbeResult:
     return ProbeResult(
         "page_lookup_s", value, "s/block", n * len(tables), spread,
         {"probe": "table_sweep_slope", "tables": list(tables),
+         "page_size": page_size,
          "slope_paged_s": slope_paged, "slope_contig_s": slope_contig})
 
 

@@ -22,8 +22,8 @@ through a KV page table (``serve.paged``), so K/V stream from a shared
 page table rides in as an extra scalar-prefetch argument next to
 qmap/kmap/last and the K/V index maps first clamp the key block to the
 slot's live span (``starts[slot] + chunk`` — the chunk's own rows included,
-write-then-attend) and then translate logical→physical before the DMA — the
-same software-TLB walk as ``flash_decode_paged``, at prefill width. The
+write-then-attend) and then translate logical→physical before the DMA — a
+software-TLB walk, as in ``flash_decode_paged``, at prefill width. The
 qmap/kmap/last enumeration is built once for the worst-case chunk position
 (the chunk ending at the pool's last row), so one executable serves every
 chunk of every prompt; blocks past a particular chunk's live span re-map to

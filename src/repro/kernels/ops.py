@@ -88,16 +88,12 @@ def flash_decode(q, k, v, lengths, block_k=None):
                                       interpret=_interpret())
 
 
-def flash_decode_paged(q, k_pages, v_pages, page_table, lengths,
-                       block_k=None):
+def flash_decode_paged(q, k_pages, v_pages, page_table, lengths):
     """Paged GQA decode: q (b, h, d) vs a (n_pages, page_size, kvh, d)
-    pool walked through ``page_table`` (b, max_pages). ``block_k`` snaps
-    to a divisor of the page size (None -> cost-model choice)."""
-    if block_k is not None:
-        block_k = _largest_divisor(k_pages.shape[1], block_k)
+    pool walked through ``page_table`` (b, max_pages), one page (every kv
+    head) per step as the pool stores it."""
     return _flash_decode.flash_decode_paged(
-        q, k_pages, v_pages, page_table, lengths, block_k=block_k,
-        interpret=_interpret())
+        q, k_pages, v_pages, page_table, lengths, interpret=_interpret())
 
 
 def ssd_scan(x, a_log, b, c, chunk: int = 128):

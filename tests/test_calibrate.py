@@ -71,6 +71,8 @@ def test_probes_cover_every_constant_finite_positive(fast_results):
 
 def test_page_lookup_probe_reports_its_regression(fast_results):
     d = fast_results["page_lookup_s"].detail
+    assert fast_results["page_lookup_s"].unit == "s/block"
+    assert d["page_size"] == 8               # one page per visited block
     assert np.isfinite(d["slope_paged_s"])
     assert np.isfinite(d["slope_contig_s"])
     assert len(d["tables"]) >= 3

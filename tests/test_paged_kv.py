@@ -8,6 +8,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import autotune
 from repro.kernels import ref
+from repro.kernels import flash_decode as fd
 from repro.kernels.flash_decode import flash_decode_paged
 from repro.serve import paged
 
@@ -187,8 +188,7 @@ def test_paged_decode_matches_contiguous_oracle(h, kvh):
     q, k, v = _case(rng, b, h, kvh, d, max_len)
     lengths = jnp.asarray([1, 17, 64, 33], jnp.int32)
     kp, vp, table = _paginate(k, v, lengths, ps, 24, rng)
-    out = flash_decode_paged(q, kp, vp, table, lengths, block_k=8,
-                             interpret=True)
+    out = flash_decode_paged(q, kp, vp, table, lengths, interpret=True)
     expect = ref.flash_decode(q, k, v, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-4, atol=2e-4)
@@ -203,31 +203,114 @@ def test_paged_decode_zero_length_slot_is_zeros_not_nan():
     kp, vp, table = _paginate(k, v, lengths, 8, 16, rng)
     assert int(table[0].sum()) == 0           # freed slot: all-null row
     out = np.asarray(flash_decode_paged(q, kp, vp, table, lengths,
-                                        block_k=8, interpret=True))
+                                        interpret=True))
     assert np.isfinite(out).all()
     np.testing.assert_array_equal(out[0], np.zeros_like(out[0]))
     expect = np.asarray(ref.flash_decode(q, k, v, lengths))
     np.testing.assert_allclose(out, expect, rtol=2e-4, atol=2e-4)
 
 
-@given(seed=st.integers(0, 50), block_k=st.sampled_from([4, 8, 16]),
+@given(seed=st.integers(0, 50), page_size=st.sampled_from([4, 8, 16]),
        kvh=st.sampled_from([1, 2, 4]))
 @settings(max_examples=10, deadline=None)
-def test_paged_decode_block_and_length_invariance(seed, block_k, kvh):
-    """Property: any dividing block size, GQA group, ragged length vector
-    and page shuffle reproduces the contiguous oracle bit-for-bit (within
-    fp tolerance)."""
+def test_paged_decode_block_and_length_invariance(seed, page_size, kvh):
+    """Property: any page size (the kernel's block), GQA group, ragged
+    length vector and page shuffle reproduces the contiguous oracle
+    (within fp tolerance)."""
     rng = np.random.RandomState(seed)
-    b, d, max_len, ps = 3, 8, 64, 16
+    b, d, max_len = 3, 8, 64
     h = kvh * int(rng.randint(1, 4))
     q, k, v = _case(rng, b, h, kvh, d, max_len)
     lengths = jnp.asarray(rng.randint(0, max_len + 1, size=b), jnp.int32)
-    kp, vp, table = _paginate(k, v, lengths, ps, 20, rng)
-    out = flash_decode_paged(q, kp, vp, table, lengths, block_k=block_k,
+    n_pages = 1 + b * max_len // page_size
+    kp, vp, table = _paginate(k, v, lengths, page_size, n_pages, rng)
+    out = flash_decode_paged(q, kp, vp, table, lengths, interpret=True)
+    expect = ref.flash_decode(q, k, v, lengths)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               rtol=2e-4, atol=2e-4)
+
+
+# The benchmark cells' decode heads: qwen3-4b (group 4, kvh 8, head_dim
+# 128) and phi3-mini (MHA, kvh 32, head_dim 96), bf16 as served, with
+# lengths of 0, a page, a page plus one and the whole table.
+@pytest.mark.parametrize("h,kvh,d", [(32, 8, 128), (32, 32, 96)],
+                         ids=["qwen3-4b", "phi3-mini"])
+def test_paged_decode_cell_heads_bf16(h, kvh, d):
+    rng = np.random.RandomState(3)
+    ps, max_pages = 16, 3
+    q, k, v = (a.astype(jnp.bfloat16) for a in
+               _case(rng, 4, h, kvh, d, ps * max_pages))
+    lengths = jnp.asarray([0, ps, ps + 1, ps * max_pages], jnp.int32)
+    kp, vp, table = _paginate(k, v, lengths, ps, 9, rng)
+    out = flash_decode_paged(q, kp.astype(jnp.bfloat16),
+                             vp.astype(jnp.bfloat16), table, lengths,
+                             interpret=True)
+    assert out.dtype == jnp.bfloat16
+    expect = ref.flash_decode(q.astype(jnp.float32), k.astype(jnp.float32),
+                              v.astype(jnp.float32), lengths)
+    out = np.asarray(out.astype(jnp.float32))
+    np.testing.assert_array_equal(out[0], np.zeros_like(out[0]))
+    # Only the output's rounding to bf16 separates it from the f32 oracle.
+    np.testing.assert_allclose(out, np.asarray(expect), rtol=1e-2,
+                               atol=1e-2)
+
+
+def test_paged_decode_shared_page_serves_both_slots():
+    """Prefix caching maps one physical page into two slots' tables; each
+    slot attends it under its own length."""
+    rng = np.random.RandomState(4)
+    ps = 8
+    q, k, v = _case(rng, 2, 4, 2, 8, 3 * ps)
+    k = k.at[1, :ps].set(k[0, :ps])
+    v = v.at[1, :ps].set(v[0, :ps])
+    lengths = jnp.asarray([2 * ps + 3, ps + 5], jnp.int32)
+    kp, vp, table = _paginate(k, v, lengths, ps, 8, rng)
+    table = np.asarray(table).copy()
+    table[1, 0] = table[0, 0]                 # the shared prefix page
+    out = flash_decode_paged(q, kp, vp, jnp.asarray(table), lengths,
                              interpret=True)
     expect = ref.flash_decode(q, k, v, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-4, atol=2e-4)
+
+
+def test_paged_decode_freed_slot_walks_no_page():
+    """A freed slot keeps a null table row while its write index drifts one
+    position per step: it gives zeros, and the walk never visits it or
+    the null page (poisoned here, so attending it would show)."""
+    rng = np.random.RandomState(5)
+    ps = 8
+    q, k, v = _case(rng, 3, 4, 2, 8, 4 * ps)
+    lengths = jnp.asarray([9, 3 * ps + 2, 21], jnp.int32)
+    kp, vp, table = _paginate(k, v, lengths, ps, 12, rng)
+    table = table.at[1].set(0)                # freed: null row, index drifts
+    kp, vp = kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan)
+    out = np.asarray(flash_decode_paged(q, kp, vp, table, lengths,
+                                        interpret=True))
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out[1], np.zeros_like(out[1]))
+    live = jnp.asarray([9, 0, 21], jnp.int32)
+    np.testing.assert_allclose(out, np.asarray(ref.flash_decode(q, k, v,
+                                                                live)),
+                               rtol=2e-4, atol=2e-4)
+    live, end, steps = fd._walk_plan(table, lengths, ps)
+    assert np.asarray(live).tolist() == [2, 0, 3] and int(steps) == 5
+    slots = [int(fd._slot_of(g, end)) for g in range(5)]
+    walked = [(i, g - int(end[i] - live[i])) for g, i in enumerate(slots)]
+    assert walked == [(0, 0), (0, 1), (2, 0), (2, 1), (2, 2)]
+    assert all(int(table[i, j]) != 0 for i, j in walked)
+
+
+@pytest.mark.parametrize("b", [1, 3, 5, 32])
+def test_walk_slot_of_every_step_matches_searchsorted(b):
+    """Grid step g walks the slot whose page range holds it; slots with
+    no page to walk (zeros in ``live``) are stepped over."""
+    rng = np.random.RandomState(b)
+    live = rng.randint(0, 4, size=b) * (rng.rand(b) < 0.7)
+    end = jnp.asarray(np.cumsum(live), jnp.int32)
+    for g in range(int(end[-1])):
+        want = int(np.searchsorted(np.asarray(end), g, side="right"))
+        assert int(fd._slot_of(g, end)) == want
 
 
 def test_gather_kv_reconstructs_contiguous_view():

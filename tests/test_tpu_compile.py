@@ -79,6 +79,24 @@ def test_flash_decode_paged_compiles_native(one_chip, native):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize(
+    "batch,n_pages,max_len,kvh,d",
+    [(32, 192, 4096, 8, 128), (5, 81, 2048, 32, 96)],
+    ids=["qwen3-4b", "phi3-mini"])
+def test_flash_decode_paged_compiles_at_cell_sizes(one_chip, native, batch,
+                                                   n_pages, max_len, kvh, d):
+    """Both benchmark cells' decode shapes (pages of 128 rows, 32 query
+    heads). phi3's 96-lane head dim pads to 128 in VMEM, so each of its
+    double-buffered K and V pages takes 1 MiB there."""
+    pool = _spec((n_pages, PAGE, kvh, d), jnp.bfloat16, one_chip)
+    q = _spec((batch, 32, d), jnp.bfloat16, one_chip)
+    table = _spec((batch, max_len // PAGE), np.int32, one_chip)
+    lengths = _spec((batch,), np.int32, one_chip)
+    text = jax.jit(kernel_ops.flash_decode_paged).lower(
+        q, pool, pool, table, lengths).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
 def test_flash_attention_paged_compiles_native(one_chip, native):
     pool, table = _pool(one_chip, 1)
     q = _spec((1, 256, CFG.n_heads, CFG.dhead), CFG.dtype, one_chip)
