@@ -1,12 +1,19 @@
-"""The plain reference: a dense decoder forward pass in float32.
+"""The dense decoder: pre-norm RMSNorm blocks, GQA attention with optional
+per-head q/k RMSNorm, rotary positions with half-split rotation, SwiGLU
+MLP, final norm and an untied output head.
 
-Written from the published architecture (pre-norm RMSNorm blocks, GQA
-attention with optional per-head q/k RMSNorm, rotary positions with
-half-split rotation, SwiGLU MLP, final norm and output head) in
-straightforward ``jax.numpy``. It imports nothing of the program and
-reads the weights the benchmark made (``bench/weights.py``). Every matmul
-runs at ``Precision.HIGHEST``: on a TPU a float32 matmul otherwise runs
-in bfloat16 passes.
+Weights: random, from the seed, made on the device in one jitted call.
+The tree is the layout the serving engine loads (a checkpoint format):
+per-layer leaves stacked over the layers, attention weights kept 3-D.
+It is built here from the configuration file, not by the program, so the
+plain reference below reads the same weights without taking anything
+the program made. Scales: norms are ones, the embedding is N(0, 1), and
+every projection is N(0, 1/fan_in).
+
+The plain reference: the forward pass in float32, written from the
+published architecture in straightforward ``jax.numpy``. It imports
+nothing of the program. Every matmul runs at ``Precision.HIGHEST``: on a
+TPU a float32 matmul otherwise runs in bfloat16 passes.
 
 ``mode="fp8"`` is the control: the same forward with every matmul's
 operands rounded to float8 e4m3 (weights scaled per tensor, activations
@@ -15,7 +22,8 @@ has to fail the comparison that the program passes.
 
 The forward runs layer by layer (``lax.scan`` over the stacked layers,
 each layer's weights cast to float32 inside the step) and attends in
-blocks of queries, so it fits beside the weights on one chip.
+blocks of queries, so it fits beside the weights on one chip. On a mesh
+it runs under ``jit`` on the weights as they are sharded.
 """
 
 from __future__ import annotations
@@ -32,6 +40,81 @@ PAD = 1024           # sequence lengths are padded to this multiple
 ROWS = 512           # logit rows are padded to this multiple
 FP8_MAX = 448.0      # largest finite float8 e4m3 value
 
+
+def program_config(T, c: dict):
+    """The program's ``ModelConfig`` from the HF-style keys of the file."""
+    return T.ModelConfig(
+        name=c["name"], n_layers=c["num_hidden_layers"],
+        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
+        n_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
+        vocab=c["vocab_size"], head_dim=c["head_dim"],
+        qk_norm=bool(c.get("qk_norm")), rope_theta=float(c["rope_theta"]),
+        compute_dtype=c["torch_dtype"])
+
+
+# -- weights ---------------------------------------------------------------
+
+def shapes(c: dict) -> dict:
+    """Leaf shapes by path, from the HF-style keys of a config file."""
+    L, d, f, V = (c["num_hidden_layers"], c["hidden_size"],
+                  c["intermediate_size"], c["vocab_size"])
+    h, kvh, hd = (c["num_attention_heads"], c["num_key_value_heads"],
+                  c["head_dim"])
+    attn = {"wq": (L, d, h, hd), "wk": (L, d, kvh, hd),
+            "wv": (L, d, kvh, hd), "wo": (L, h, hd, d)}
+    if c.get("qk_norm"):
+        attn["q_norm"] = {"scale": (L, hd)}
+        attn["k_norm"] = {"scale": (L, hd)}
+    return {
+        "embed": {"embedding": (V, d)},
+        "blocks": [{
+            "ln1": {"scale": (L, d)},
+            "attn": attn,
+            "ln2": {"scale": (L, d)},
+            "mlp": {"w_gate": (L, d, f), "w_up": (L, d, f),
+                    "w_down": (L, f, d)},
+        }],
+        "ln_f": {"scale": (d,)},
+        "unembed": {"lm_head": (d, V)},
+    }
+
+
+def _fan_in(name: str, shape) -> float:
+    if name == "embedding":
+        return 1.0
+    if name == "wo":                      # (L, h, hd, d)
+        return float(shape[1] * shape[2])
+    if name == "lm_head":                 # (d, V)
+        return float(shape[0])
+    return float(shape[1])                # (L, in, ...)
+
+
+def make(c: dict, key, shardings=None):
+    """Every leaf in the file's ``torch_dtype``; with ``shardings`` (a
+    tree of shardings like ``shapes(c)``'s) each leaf is made in its
+    place, so no device ever holds the whole tree."""
+    dtype = jnp.dtype(c["torch_dtype"])
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(
+        shapes(c), is_leaf=lambda x: isinstance(x, tuple))
+
+    def build(key):
+        out = []
+        for i, (path, shape) in enumerate(leaves):
+            name = path[-1].key
+            if name == "scale":
+                out.append(jnp.ones(shape, dtype))
+                continue
+            k = jax.random.fold_in(key, i)
+            out.append((jax.random.normal(k, shape, jnp.float32)
+                        * _fan_in(name, shape) ** -0.5).astype(dtype))
+        return jax.tree.unflatten(treedef, out)
+
+    if shardings is None:
+        return jax.jit(build)(key)
+    return jax.jit(build, out_shardings=shardings)(key)
+
+
+# -- the reference ---------------------------------------------------------
 
 def _q8(x, axes):
     s = jnp.max(jnp.abs(x), axis=axes, keepdims=True) / FP8_MAX
@@ -135,3 +218,24 @@ def logits(params, c: dict, tokens: np.ndarray, rows: np.ndarray,
     out = _forward(params, jnp.asarray(toks), jnp.asarray(idx),
                    statics=statics, fp8=(mode == "fp8"))
     return out[:len(rows)]
+
+
+# -- counts for bench/work.py ------------------------------------------------
+
+def _itemsize(c: dict) -> int:
+    return {"bfloat16": 2, "float16": 2, "float32": 4}[c["torch_dtype"]]
+
+
+def layer_matmul_params(c: dict) -> int:
+    """Weights one token multiplies through in one layer."""
+    d, h, kvh, hd, f = (c["hidden_size"], c["num_attention_heads"],
+                        c["num_key_value_heads"], c["head_dim"],
+                        c["intermediate_size"])
+    return d * h * hd + 2 * d * kvh * hd + h * hd * d + 3 * d * f
+
+
+def kv_bytes(c: dict, rows: int) -> int:
+    """K and V of ``rows`` positions in all layers."""
+    L, kvh, hd = (c["num_hidden_layers"], c["num_key_value_heads"],
+                  c["head_dim"])
+    return 2 * L * rows * kvh * hd * _itemsize(c)

@@ -42,7 +42,7 @@ class Run:
     cell: str
     config: dict
     mix: dict
-    peak: dict
+    peak: dict                              # the cell's chips together
     batch: int
     setup_s: float
     t_open: float
@@ -113,18 +113,21 @@ def device(run: Run) -> Optional[dict]:
 
 
 def module_s(run: Run, name: str) -> List[float]:
-    """Device seconds of each launch of the executable ``name``."""
+    """Device seconds of each launch of the executable ``name``, on the
+    first of the cell's chips: on a mesh every chip runs each launch."""
     dev = device(run)
     return list(dev["modules_s"].get(name, [])) if dev else []
 
 
 def op_s(run: Run, pattern: str) -> float:
-    """Device seconds of the ops whose names match ``pattern``."""
-    dev = device(run)
-    if not dev:
+    """Device seconds of the ops whose names match ``pattern``, the mean
+    over the cell's chips."""
+    if not run.trace:
         return 0.0
     rx = re.compile(pattern)
-    return sum(v for k, v in dev["ops_s"].items() if rx.search(k))
+    devs = run.trace["devices"]
+    return sum(v for d in devs for k, v in d["ops_s"].items()
+               if rx.search(k)) / len(devs)
 
 
 def share(need_s: float, took_s: float) -> Optional[float]:

@@ -11,6 +11,12 @@ the traffic uses, then drives ``submit``/``tick`` with the mix's requests
 at their due times and measures for ``--seconds``. After the window it
 compares what the engine served with the plain float32 reference.
 
+The configuration's ``"arch"`` names its architecture module,
+``bench/arch/<arch>.py``: weights, reference and work counts. A cell on
+several chips serves on the program's tensor-parallel mesh over the
+first ``chips`` devices, with every weight made where the engine keeps
+it; its device numbers are per chip over those devices.
+
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
 with ``--trace 1`` its per-layer metrics read from a profiler trace of
@@ -42,7 +48,7 @@ TRACE_DIR = os.path.join(ROOT, "bench", ".trace")
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from bench import gen, readers, reference, weights  # noqa: E402
+from bench import arch, gen, readers  # noqa: E402
 from bench import trace as trace_mod  # noqa: E402
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -101,8 +107,8 @@ def layout(root: str, cell_name: str) -> dict:
     per_layer = [m for m in bm["per_layer"]
                  if (cell_name in m["workloads"] if "workloads" in m
                      else m["moves"] in names)]
-    return {"cell": cell, "config": config, "mix": mix,
-            "end_to_end": e2e, "per_layer": per_layer,
+    return {"cell": cell, "config": config, "arch": arch.load(config),
+            "mix": mix, "end_to_end": e2e, "per_layer": per_layer,
             "readers": {m["name"]: reader(root, m["name"])
                         for m in e2e + per_layer}}
 
@@ -129,42 +135,56 @@ def reader(root: str, name: str):
     return mod.read
 
 
-def peak_for(kind: str) -> dict:
+def peak_for(dev) -> dict:
+    """One chip's peaks from ``peaks.json``; none off a TPU, where no
+    reading is a device's."""
+    if dev.platform != "tpu":
+        return {}
     with open(os.path.join(ROOT, "bench", "peaks.json")) as f:
         peaks = json.load(f)
-    if kind not in peaks:
-        raise SystemExit(f"no peaks for device kind {kind!r} in peaks.json")
-    return peaks[kind]
+    if dev.device_kind not in peaks:
+        raise SystemExit(f"no peaks for device kind {dev.device_kind!r} "
+                         f"in peaks.json")
+    return peaks[dev.device_kind]
+
+
+def chips_peak(devs) -> dict:
+    """The peaks of the cell's chips together: a share of it divides the
+    whole model's work by every chip's peak over one chip's time."""
+    return {k: v * len(devs) if isinstance(v, (int, float)) else v
+            for k, v in peak_for(devs[0]).items()}
+
+
+def peak_bytes(dev) -> int:
+    return int((dev.memory_stats() or {}).get("peak_bytes_in_use", 0))
 
 
 # -- the program under test --------------------------------------------------
 
-def model_config(T, c: dict):
-    return T.ModelConfig(
-        name=c["name"], n_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-        vocab=c["vocab_size"], head_dim=c["head_dim"],
-        qk_norm=bool(c.get("qk_norm")), rope_theta=float(c["rope_theta"]),
-        compute_dtype=c["torch_dtype"])
-
-
-def build(c: dict, seed: int):
-    """Weights from the seed, and the engine that serves them."""
+def build(a, c: dict, seed: int, chips: int):
+    """Weights from the seed, and the engine that serves them. On several
+    chips the engine serves on the program's tensor-parallel mesh, and
+    each weight is made in the engine's own layout, so no chip ever
+    holds the whole tree."""
+    from repro.launch.mesh import make_serving_mesh
     from repro.models import transformer as T
+    from repro.serve import dist as serve_dist
     from repro.serve.engine import ServeConfig, ServingEngine
-    mcfg = model_config(T, c)
+    mcfg = a.program_config(T, c)
     key = jax.random.PRNGKey(int(gen.rng_for(seed, 0).integers(2**31)))
     want = jax.eval_shape(lambda k: T.init_params(k, mcfg, dtype=mcfg.dtype),
                           key)
-    got = jax.eval_shape(lambda k: weights.make(c, k), key)
+    got = jax.eval_shape(lambda k: a.make(c, k), key)
     if jax.tree.structure(want) != jax.tree.structure(got) or any(
-            (a.shape, a.dtype) != (b.shape, b.dtype) for a, b in
+            (x.shape, x.dtype) != (y.shape, y.dtype) for x, y in
             zip(jax.tree.leaves(want), jax.tree.leaves(got))):
         raise SystemExit("the benchmark's weight tree does not match the "
                          "program's parameter layout")
+    mesh = make_serving_mesh(tp=chips)
+    shardings = None if mesh is None else \
+        serve_dist.param_shardings(got, mesh)
     t = time.time()
-    params = weights.make(c, key)
+    params = a.make(c, key, shardings)
     jax.block_until_ready(params)
     t_w = time.time() - t
     sv = c["serving"]
@@ -172,7 +192,7 @@ def build(c: dict, seed: int):
                        page_size=sv["page_size"], n_pages=sv["n_pages"],
                        chunk_size=sv["chunk"], eos_id=-1)
     t = time.time()
-    engine = ServingEngine(params, mcfg, scfg)
+    engine = ServingEngine(params, mcfg, scfg, mesh=mesh)
     jax.block_until_ready(engine.caches)
     return params, engine, t_w, time.time() - t
 
@@ -260,8 +280,9 @@ def serve(engine, reqs, mix, seconds, tracing, compiles):
             compiles_in = compiles.names[compiles_open:]
             backlog = len(engine.queue) + len(reqs) - nxt
             if tracing:
+                # The trace is stopped once the loop ends: stopping takes
+                # tens of seconds, and no request may wait behind it.
                 window_ann.__exit__(None, None, None)
-                jax.profiler.stop_trace()
             state = "tail"
         if state == "tail":
             due = [x for x in recs.values() if due_lo <= x.due < due_hi]
@@ -292,12 +313,17 @@ def serve(engine, reqs, mix, seconds, tracing, compiles):
                     first_done = True
             if state == "window":
                 ticks.append((t_a, t_b, n_dec))
+    stop = None
+    if tracing:
+        t_a = clock() - t0
+        jax.profiler.stop_trace()
+        stop = (t_a - t_close, clock() - t0 - t_a)
     return {"recs": [recs[r.rid] for r in reqs],
             "t_open": t_open, "t_close": t_close, "due_lo": due_lo,
             "due_hi": due_hi, "ticks": ticks,
             "late": late, "compiles_in": compiles_in,
             "backlog": backlog, "tick_open": tick_open,
-            "tick_close": tick_close}
+            "tick_close": tick_close, "stop": stop}
 
 
 # -- correctness ----------------------------------------------------------------
@@ -317,7 +343,7 @@ def sample(done, seed, mix):
     return pick
 
 
-def logit_gaps(params, c, picked, mode="float32"):
+def logit_gaps(a, params, c, picked, mode="float32"):
     """Per request, the widest gap by which a served token's reference
     logit lies below the reference's best at its position. With
     ``mode="fp8"`` the tokens judged are the control's own first
@@ -326,9 +352,9 @@ def logit_gaps(params, c, picked, mode="float32"):
     for rid, prompt, served in picked:
         toks = np.concatenate([prompt, np.asarray(served[:-1], np.int32)])
         rows = np.arange(len(prompt) - 1, len(prompt) - 1 + len(served))
-        ref = reference.logits(params, c, toks, rows, "float32")
+        ref = a.logits(params, c, toks, rows, "float32")
         pick = np.asarray(served) if mode == "float32" else np.asarray(
-            jax.numpy.argmax(reference.logits(params, c, toks, rows, mode),
+            jax.numpy.argmax(a.logits(params, c, toks, rows, mode),
                              axis=-1))
         best = jax.numpy.max(ref, axis=-1)
         got = jax.numpy.take_along_axis(ref, pick[:, None], axis=-1)[:, 0]
@@ -373,13 +399,13 @@ def execute(root, cell_name, seed, seconds, tracing, mutate=None,
     ``correct``, ``checks`` and gaps) and in lines that start
     ``control check``."""
     lay = layout(root, cell_name)
-    c, mix, cell = lay["config"], lay["mix"], lay["cell"]
+    c, mix, cell, a = lay["config"], lay["mix"], lay["cell"], lay["arch"]
     sv = c["serving"]
-    dev0 = jax.devices()[0]
-    peak = peak_for(dev0.device_kind) if dev0.platform == "tpu" else {}
+    devs = jax.devices()[:cell["chips"]]
+    peak = chips_peak(devs)
     t = time.time()
     with Compiles() as compiles:
-        params, engine, t_w, t_e = build(c, seed)
+        params, engine, t_w, t_e = build(a, c, seed, len(devs))
         if mutate is not None:
             mutate(engine)
         warm(engine, sv)
@@ -391,11 +417,16 @@ def execute(root, cell_name, seed, seconds, tracing, mutate=None,
             f"{t_w:.3f}, engine {t_e:.3f}, warm-up {t_warm:.3f}; "
             f"{compiles.n} executables compiled or read from the cache")
         out = serve(engine, reqs, mix, seconds, tracing, compiles)
-    mem = (dev0.memory_stats() or {}).get("peak_bytes_in_use", 0)
+    mem = max(peak_bytes(d) for d in devs)
     red = None
     if tracing:
-        red = trace_mod.reduce(trace_mod.load(TRACE_DIR))
+        t = time.time()
+        red = trace_mod.reduce(trace_mod.load(TRACE_DIR),
+                               {d.id for d in devs})
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        after, took = out["stop"]
+        log(f"trace stopped {after:.3f} s after the window closed, in "
+            f"{took:.3f} s; read in {time.time() - t:.3f} s")
     run = readers.Run(
         cell=cell_name, config=c, mix=mix, peak=peak, batch=sv["batch"],
         setup_s=setup_s, t_open=out["t_open"], t_close=out["t_close"],
@@ -431,7 +462,7 @@ def execute(root, cell_name, seed, seconds, tracing, mutate=None,
     engine = None
     gc.collect()
     t = time.time()
-    gaps = logit_gaps(params, c, picked)
+    gaps = logit_gaps(a, params, c, picked)
     log(f"reference over {len(picked)} requests, "
         f"{sum(len(x[2]) for x in picked)} served tokens, "
         f"{time.time() - t:.3f} s: gaps {gaps}")
@@ -439,8 +470,8 @@ def execute(root, cell_name, seed, seconds, tracing, mutate=None,
             out["backlog"] if mix["window"] == "first_finish" else None)
     ok, checks = judge(gaps, *args)
     lines = check_lines(checks)
-    device = {"platform": dev0.platform, "kind": dev0.device_kind,
-              "count": len(jax.devices()), "memory_peak_bytes": int(mem)}
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs), "memory_peak_bytes": mem}
     result = {"correct": bool(ok), "attempted": len(attempted),
               "failed": len(failed), "metrics": metrics, "device": device}
     if red is not None:
@@ -448,7 +479,7 @@ def execute(root, cell_name, seed, seconds, tracing, mutate=None,
         device["window_s"] = red["window_s"]
         result["breakdown"] = trace_mod.breakdown(red)
     if control:
-        c_gaps = logit_gaps(params, c, picked, "fp8")
+        c_gaps = logit_gaps(a, params, c, picked, "fp8")
         c_ok, c_checks = judge(c_gaps, *args)
         result["control"] = {
             "correct": bool(c_ok), "gaps": c_gaps, "program_gaps": gaps,

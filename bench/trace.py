@@ -17,8 +17,9 @@ each operation name and each executable's launches.
 from __future__ import annotations
 
 import glob
+import heapq
 import re
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 Interval = Tuple[float, float]
 WINDOW = "bench.window"
@@ -54,11 +55,25 @@ def gaps(busy: Sequence[Interval], lo: float, hi: float) -> List[Interval]:
     return [g for g in out if g[1] > g[0]]
 
 
-def label(gap: Interval, spans: Sequence[Tuple[float, float, str]]) -> str:
-    """Name of the shortest host span around the gap's middle."""
-    mid = 0.5 * (gap[0] + gap[1])
-    inside = [(b - a, n) for a, b, n in spans if a <= mid <= b]
-    return min(inside)[1] if inside else "none"
+def labels(gs: Sequence[Interval],
+           spans: Sequence[Tuple[float, float, str]]) -> List[str]:
+    """For each of the gaps ``gs`` (in time order, as ``gaps`` gives
+    them), the name of the shortest host span around its middle, or
+    "none". One sweep over the spans by start, keeping those still open:
+    a window holds millions of gaps and thousands of spans."""
+    order = sorted(spans)
+    open_: List[Tuple[float, float, str]] = []    # heap by end
+    out, i = [], 0
+    for g in gs:
+        mid = 0.5 * (g[0] + g[1])
+        while i < len(order) and order[i][0] <= mid:
+            a, b, n = order[i]
+            heapq.heappush(open_, (b, b - a, n))
+            i += 1
+        while open_ and open_[0][0] < mid:
+            heapq.heappop(open_)
+        out.append(min((d, n) for _, d, n in open_)[1] if open_ else "none")
+    return out
 
 
 CONTAINERS = ("while", "conditional", "call")
@@ -108,21 +123,31 @@ def load(run_dir: str) -> dict:
     return {"devices": devices, "host": host}
 
 
-def reduce(raw: dict) -> dict:
+def plane_id(name: str) -> int:
+    """``/device:TPU:3`` -> 3, the JAX device id."""
+    return int(name.split(":")[-1])
+
+
+def reduce(raw: dict, keep: Optional[Set[int]] = None) -> dict:
     """Busy and idle time, op and executable times, inside the window.
 
-    Times of ops and executables are per device plane; ``busy_s`` is the
-    mean over the planes, and idle gaps are those of the first plane."""
+    Only the planes of the devices with ids in ``keep`` count, where it
+    is given: those of the cell's chips. Times of ops and executables are
+    per device plane; ``busy_s`` is the mean over the planes, and idle
+    gaps are those of the first plane."""
     wins = [(a, b) for a, b, n in raw["host"] if n == WINDOW]
-    if not wins or not raw["devices"]:
+    names = sorted((n for n in raw["devices"]
+                    if keep is None or plane_id(n) in keep), key=plane_id)
+    if not wins or not names:
         raise ValueError("trace holds no window span or no device plane")
     lo, hi = wins[0]
     spans = [s for s in raw["host"] if s[2] != WINDOW]
     per_dev = []
-    for name in sorted(raw["devices"], key=lambda n: int(n.split(":")[-1])):
+    for name in names:
         dev = raw["devices"][name]
         ops = [(a, b, n) for a, b, n in dev["ops"] if lo <= a and b <= hi]
         busy = union(clip([(a, b) for a, b, _ in dev["ops"]], lo, hi))
+        idle = gaps(busy, lo, hi)
         op_s: Dict[str, float] = {}
         for a, b, n in ops:
             op_s[n] = op_s.get(n, 0.0) + (b - a)
@@ -135,8 +160,8 @@ def reduce(raw: dict) -> dict:
             "busy_s": sum(b - a for a, b in busy),
             "ops_s": op_s,
             "modules_s": mods,
-            "idle_gaps": sorted(((label(g, spans), g[1] - g[0])
-                                 for g in gaps(busy, lo, hi)),
+            "idle_gaps": sorted(zip(labels(idle, spans),
+                                    (g[1] - g[0] for g in idle)),
                                 key=lambda x: -x[1]),
         })
     return {

@@ -5,17 +5,22 @@ attention over the live context only (never ``max_len`` or the pool),
 one output-head row per sampled token, weights read once per step. So a
 change that removes a copy or a kernel can raise a share but never push
 it past the chip's peak. A multiply-add counts as two operations.
+
+The counts that depend on the layers' shapes (weights a token multiplies
+through, K and V bytes per position) come from the configuration's
+architecture module, ``bench/arch/<arch>.py``.
 """
 
 from __future__ import annotations
 
 from typing import Iterable, Tuple
 
+from bench import arch
+
 
 def _dims(c: dict):
     return (c["num_hidden_layers"], c["hidden_size"],
-            c["num_attention_heads"], c["num_key_value_heads"],
-            c["head_dim"], c["intermediate_size"], c["vocab_size"])
+            c["num_attention_heads"], c["head_dim"], c["vocab_size"])
 
 
 def _itemsize(c: dict) -> int:
@@ -24,19 +29,18 @@ def _itemsize(c: dict) -> int:
 
 def layer_matmul_params(c: dict) -> int:
     """Weights one token multiplies through in one layer."""
-    _, d, h, kvh, hd, f, _ = _dims(c)
-    return d * h * hd + 2 * d * kvh * hd + h * hd * d + 3 * d * f
+    return arch.load(c).layer_matmul_params(c)
 
 
 def attention_flops(c: dict, queries_keys: int) -> float:
     """QK^T and PV over ``queries_keys`` (query, key) pairs, all layers."""
-    L, _, h, _, hd, _, _ = _dims(c)
+    L, _, h, hd, _ = _dims(c)
     return 4.0 * L * h * hd * queries_keys
 
 
 def token_flops(c: dict, ctx: int, head: bool = True) -> float:
     """One token through the model, attending ``ctx`` keys."""
-    L, d, _, _, _, _, V = _dims(c)
+    L, d, _, _, V = _dims(c)
     dense = 2.0 * L * layer_matmul_params(c) + (2.0 * d * V if head else 0)
     return dense + attention_flops(c, ctx)
 
@@ -48,7 +52,7 @@ def chunk_pairs(start: int, rows: int) -> int:
 
 def chunk_flops(c: dict, start: int, rows: int, head: bool) -> float:
     """A prefill chunk; ``head`` when its last row's logits are sampled."""
-    L, d, _, _, _, _, V = _dims(c)
+    L, d, _, _, V = _dims(c)
     return (2.0 * L * layer_matmul_params(c) * rows
             + attention_flops(c, chunk_pairs(start, rows))
             + (2.0 * d * V if head else 0.0))
@@ -56,13 +60,12 @@ def chunk_flops(c: dict, start: int, rows: int, head: bool) -> float:
 
 def kv_bytes(c: dict, rows: int) -> int:
     """K and V of ``rows`` positions in all layers."""
-    L, _, _, kvh, hd, _, _ = _dims(c)
-    return 2 * L * rows * kvh * hd * _itemsize(c)
+    return arch.load(c).kv_bytes(c, rows)
 
 
 def qo_bytes(c: dict, rows: int) -> int:
     """Query in and attention out of ``rows`` positions, all layers."""
-    L, _, h, _, hd, _, _ = _dims(c)
+    L, _, h, hd, _ = _dims(c)
     return 2 * L * rows * h * hd * _itemsize(c)
 
 

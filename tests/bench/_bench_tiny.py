@@ -16,7 +16,7 @@ for _p in (REPO, os.path.join(REPO, "src")):
         sys.path.insert(0, _p)
 
 CONFIG = {
-    "name": "tiny", "hidden_size": 64, "intermediate_size": 128,
+    "name": "tiny", "arch": "dense", "hidden_size": 64, "intermediate_size": 128,
     "num_hidden_layers": 2, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 256,
     "rope_theta": 10000.0, "rms_norm_eps": 1e-06,

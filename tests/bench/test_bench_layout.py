@@ -12,7 +12,8 @@ import numpy as np
 import pytest
 
 import _bench_tiny as tb
-from bench import gen, reference, run, weights
+from bench import gen, run
+from bench.arch import dense
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -181,11 +182,11 @@ def test_weights_match_the_programs_parameter_layout(name):
     from repro.models import transformer as T
     with open(os.path.join(tb.REPO, "bench", "configs", name + ".json")) as f:
         c = json.load(f)
-    mcfg = run.model_config(T, c)
+    mcfg = dense.program_config(T, c)
     key = jax.random.PRNGKey(0)
     want = jax.eval_shape(lambda k: T.init_params(k, mcfg, dtype=mcfg.dtype),
                           key)
-    got = jax.eval_shape(lambda k: weights.make(c, k), key)
+    got = jax.eval_shape(lambda k: dense.make(c, k), key)
     assert jax.tree.structure(want) == jax.tree.structure(got)
     assert [(a.shape, a.dtype) for a in jax.tree.leaves(want)] == \
         [(a.shape, a.dtype) for a in jax.tree.leaves(got)]
@@ -197,10 +198,10 @@ def test_reference_agrees_with_the_programs_float32_forward():
     tiny size the two compute the same logits."""
     from repro.models import transformer as T
     c = dict(tb.CONFIG, torch_dtype="float32")
-    params = weights.make(c, jax.random.PRNGKey(3))
+    params = dense.make(c, jax.random.PRNGKey(3))
     toks = np.random.default_rng(0).integers(0, 256, 40).astype(np.int32)
-    want, _, _ = T.forward(params, run.model_config(T, c), toks[None])
+    want, _, _ = T.forward(params, dense.program_config(T, c), toks[None])
     rows = np.arange(40)
-    got = reference.logits(params, c, toks, rows)
+    got = dense.logits(params, c, toks, rows)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want[0]),
                                atol=2e-4, rtol=2e-4)

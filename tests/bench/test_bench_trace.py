@@ -3,6 +3,7 @@ short trace recorded on a TPU v5e kept under ``bench/fixtures``."""
 
 import os
 
+import numpy as np
 import pytest
 
 import _bench_tiny as tb
@@ -25,9 +26,8 @@ def test_gaps_and_clip():
 
 def test_gap_takes_the_innermost_span():
     spans = [(0, 10, "bench.tick"), (4, 6, "bench.record")]
-    assert trace.label((4.5, 5.5), spans) == "bench.record"
-    assert trace.label((1, 2), spans) == "bench.tick"
-    assert trace.label((11, 12), spans) == "none"
+    assert trace.labels([(1, 2), (4.5, 5.5), (11, 12)], spans) == \
+        ["bench.tick", "bench.record", "none"]
 
 
 def test_module_names_drop_the_launch_id():
@@ -69,3 +69,44 @@ def test_recorded_trace_names_the_kernel_and_the_step(recorded):
     # Ops overlap at most where nested; their sum covers the busy time.
     assert sum(dev["ops_s"].values()) >= recorded["busy_s"] * 0.999
     assert trace.breakdown(recorded)["device_ops"]
+
+
+def test_reduce_keeps_only_the_cells_chips():
+    """Planes of devices outside the cell are left out; busy time is the
+    mean over the cell's planes, ops and launches are kept per plane."""
+    dev = lambda lo, hi: {"ops": [(lo, hi, "a")],  # noqa: E731
+                          "modules": [(lo, hi, "jit_step(1)")]}
+    raw = {"host": [(0.0, 10.0, "bench.window")],
+           "devices": {"/device:TPU:2": dev(1.0, 5.0),
+                       "/device:TPU:0": dev(1.0, 3.0),
+                       "/device:TPU:10": dev(0.0, 10.0)}}
+    red = trace.reduce(raw, {0, 2})
+    assert [d["name"] for d in red["devices"]] == ["/device:TPU:0",
+                                                   "/device:TPU:2"]
+    assert red["busy_s"] == 3.0
+    assert trace.reduce(raw)["busy_s"] == 16.0 / 3
+    with pytest.raises(ValueError):
+        trace.reduce(raw, {5})
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_labels_in_one_sweep_match_the_plain_rule(seed):
+    """``labels`` names each gap as the plain rule does: the shortest
+    span (then the least name) with start <= middle <= end."""
+    rng = np.random.default_rng(seed)
+    spans = []
+    for _ in range(300):
+        a = float(rng.integers(0, 400)) / 4
+        spans.append((a, a + float(rng.integers(0, 40)) / 4,
+                      f"bench.s{rng.integers(0, 5)}"))
+    busy = trace.union([(float(a), float(a) + 0.25) for a in
+                        rng.integers(0, 500, 200) / 4])
+    gs = trace.gaps(busy, 0.0, 130.0)
+
+    def plain(g):
+        mid = 0.5 * (g[0] + g[1])
+        inside = [(b - a, n) for a, b, n in spans if a <= mid <= b]
+        return min(inside)[1] if inside else "none"
+
+    assert len(gs) > 50
+    assert trace.labels(gs, spans) == [plain(g) for g in gs]

@@ -6,7 +6,8 @@ import os
 import pytest
 
 import _bench_tiny as tb
-from bench import readers, weights, work
+from bench import readers, work
+from bench.arch import dense
 
 C = tb.CONFIG            # L=2, d=64, h=4, kvh=2, hd=16, f=128, V=256, bf16
 
@@ -80,8 +81,8 @@ def test_roofline_names_its_bound():
                          ids=["tiny", "qwen3-4b", "phi3-mini-3.8b"])
 def test_token_flops_match_the_weights_a_token_multiplies(cfg):
     """Twice the matmul weights of every layer and the head, whatever
-    the layout that ``bench/weights.py`` makes."""
-    shapes = weights.shapes(cfg)
+    the layout that ``bench/arch/dense.py`` makes."""
+    shapes = dense.shapes(cfg)
     n = 0
     for blk in shapes["blocks"]:
         for name in ("wq", "wk", "wv", "wo"):
@@ -100,8 +101,23 @@ def test_published_sizes(name, params):
     phi3-mini."""
     cfg = _published(name)
     total = 0
-    for leaf in (weights.shapes(cfg)["embed"]["embedding"],
-                 weights.shapes(cfg)["unembed"]["lm_head"]):
+    for leaf in (dense.shapes(cfg)["embed"]["embedding"],
+                 dense.shapes(cfg)["unembed"]["lm_head"]):
         total += leaf[0] * leaf[1]
     total += cfg["num_hidden_layers"] * work.layer_matmul_params(cfg)
     assert abs(total / params - 1) < 0.005, total
+
+
+def test_kernel_time_is_the_mean_over_the_cells_chips():
+    """A kernel's device time is the mean over the cell's planes; an
+    executable's launches are those of the first plane, since every chip
+    of a mesh runs each launch."""
+    run = _run([])
+    run.trace = {"window_s": 1.0, "busy_s": 0.5, "devices": [
+        {"ops_s": {"flash_decode_paged.1": 0.3, "copy.2": 0.1},
+         "modules_s": {"jit_step": [0.1, 0.2]}},
+        {"ops_s": {"flash_decode_paged.1": 0.5},
+         "modules_s": {"jit_step": [0.3, 0.4]}}]}
+    assert readers.op_s(run, "flash_decode_paged") == pytest.approx(0.4)
+    assert readers.op_s(run, "copy") == pytest.approx(0.05)
+    assert readers.module_s(run, "jit_step") == [0.1, 0.2]
